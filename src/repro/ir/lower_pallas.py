@@ -42,6 +42,7 @@ indexed-update scatters, which Mosaic cannot lower.
 from __future__ import annotations
 
 import functools
+import re
 from typing import Callable, Mapping
 
 import jax
@@ -62,6 +63,13 @@ from repro.obs import metrics
 from repro.runtime import pallas_interpret
 
 Array = jax.Array
+
+
+def _kernel_name(program: StencilProgram) -> str:
+    """The ``pallas_call`` name of ``program``'s kernel, which names it in
+    jaxprs, compiled HLO and device traces: ``ir_<program>`` made an
+    identifier."""
+    return re.sub(r"\W", "_", f"ir_{program.name}")
 
 
 def _generic_kernel(
@@ -216,6 +224,7 @@ def lower_pallas(
     resident = resident_blocks(
         n_haloed, len(fields) - n_haloed, len(program.outputs)
     )
+    kernel_name = _kernel_name(program)
 
     @functools.partial(
         jax.jit, static_argnames=("br", "rows_p", "interp", "col_sharded")
@@ -229,7 +238,8 @@ def lower_pallas(
             # (the bottom ring keeps its own values), so the pad is never read
             # into a kept output and is cropped off below.
             pad = ((0, 0), (0, rows_p - rows), (0, 0))
-            arrays = [jnp.pad(a, pad) for a in arrays]
+            with jax.named_scope(f"ir/{program.name}/pad"):
+                arrays = [jnp.pad(a, pad) for a in arrays]
         row_tiles = rows_p // br
         meta = jnp.stack(
             [
@@ -269,33 +279,36 @@ def lower_pallas(
         out_fields = tuple(program.outputs)
         if len(out_fields) == 1:
             state = arrays[fields.index(program.passthrough)]
-            out = pl.pallas_call(
+            outs = [pl.pallas_call(
                 kernel,
                 grid=(depth, row_tiles),
                 in_specs=in_specs,
                 out_specs=spec(lambda d, i: (d, i, 0)),
                 out_shape=jax.ShapeDtypeStruct(state.shape, state.dtype),
                 interpret=interp,
+                name=kernel_name,
+            )(*operands, meta)]
+        else:
+            # Coupled systems: one fused kernel writes every evolving field's
+            # updated block — N output refs, one VMEM residency.
+            outs = pl.pallas_call(
+                kernel,
+                grid=(depth, row_tiles),
+                in_specs=in_specs,
+                out_specs=[spec(lambda d, i: (d, i, 0)) for _ in out_fields],
+                out_shape=[
+                    jax.ShapeDtypeStruct(
+                        arrays[fields.index(f)].shape, arrays[fields.index(f)].dtype
+                    )
+                    for f in out_fields
+                ],
+                interpret=interp,
+                name=kernel_name,
             )(*operands, meta)
-            return out[:, :rows] if rows_p > rows else out
-        # Coupled systems: one fused kernel writes every evolving field's
-        # updated block — N output refs, one VMEM residency.
-        outs = pl.pallas_call(
-            kernel,
-            grid=(depth, row_tiles),
-            in_specs=in_specs,
-            out_specs=[spec(lambda d, i: (d, i, 0)) for _ in out_fields],
-            out_shape=[
-                jax.ShapeDtypeStruct(
-                    arrays[fields.index(f)].shape, arrays[fields.index(f)].dtype
-                )
-                for f in out_fields
-            ],
-            interpret=interp,
-        )(*operands, meta)
         if rows_p > rows:
-            outs = [o[:, :rows] for o in outs]
-        return dict(zip(out_fields, outs))
+            with jax.named_scope(f"ir/{program.name}/crop"):
+                outs = [o[:, :rows] for o in outs]
+        return outs[0] if len(out_fields) == 1 else dict(zip(out_fields, outs))
 
     def fn(x: Array | Mapping[str, Array], *, row_offset=0, rows_global=None,
            col_offset=0, cols_global=None) -> Array:
@@ -335,6 +348,8 @@ def lower_pallas(
 
 
 def _lower_pallas_1d(program, *, interpret):
+    kernel_name = _kernel_name(program)
+
     @functools.partial(jax.jit, static_argnames=("interp",))
     def _call(x, interp):
         batch, n = x.shape
@@ -351,6 +366,7 @@ def _lower_pallas_1d(program, *, interpret):
             out_specs=pl.BlockSpec((bb, n), lambda b: (b, 0)),
             out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
             interpret=interp,
+            name=kernel_name,
         )(x)
 
     def fn(x: Array) -> Array:

@@ -10,8 +10,10 @@ Seven small pieces (see README "Observability"):
     via ``axis_names``) and the cadence/policy ``HealthMonitor`` that makes
     long forecasts blow-up-safe.
   * :mod:`repro.obs.events`  — the flight recorder: bounded ring of
-    structured events, span helpers, ``REPRO_EVENT_LOG`` JSONL sink and a
-    crash dump that flushes the ring on abort.
+    structured events, ``REPRO_EVENT_LOG`` JSONL sink and a crash dump
+    that flushes the ring on abort; and spans, the one timing primitive:
+    an always-on ring of named, nested, timed spans that also annotate
+    any profiler capture.
   * :mod:`repro.obs.export`  — Prometheus-style text exposition of the
     metrics snapshot (health gauges included).
   * :mod:`repro.obs.drift`   — model-vs-measured drift detection (the

@@ -1,13 +1,13 @@
-"""Flight recorder: a bounded ring of structured run events + JSONL sink.
+"""Flight recorder and spans: bounded rings of run events and timed spans.
 
 The run-health counterpart of :mod:`repro.obs.metrics`: where the registry
 aggregates (counters/gauges/timers), the recorder keeps the *sequence* —
 what happened, in what order, right up to the moment a long forecast blew
 up. Design constraints mirror the metrics switchboard:
 
-  * **Zero overhead when disabled.** No recorder installed means every
-    module hook (:func:`record`, :func:`span`, :func:`crash_dump`) is one
-    attribute check; ``span`` hands back a shared no-op context manager.
+  * **Zero overhead when disabled.** No recorder installed means the
+    module hooks :func:`record` and :func:`crash_dump` are one attribute
+    check each.
   * **Bounded memory.** The ring holds the last ``capacity`` events
     (``deque(maxlen=...)``); older events are dropped (and counted in
     ``dropped``) — a million-step forecast can record every probe without
@@ -22,18 +22,37 @@ up. Design constraints mirror the metrics switchboard:
 
 Event timestamps are ``time.monotonic()`` (ordering/durations are immune
 to wall-clock steps) plus ``time.time()`` for cross-run correlation.
+
+Spans (:func:`span`, :func:`record_span`) are the one timing primitive of
+the instrumented layers, and they are always on. Each finished span is a
+:class:`Span` (name, ``time.perf_counter_ns()`` start and end, its own id,
+the id of the span open around it, and an optional request id and batch
+number) in a module-level ring of the last :data:`SPAN_CAPACITY` spans
+(:func:`spans`, :func:`spans_dropped`). A ``with`` span also opens a
+``jax.profiler.TraceAnnotation`` of its name, so a captured profiler trace
+shows it on the host plane, on the device ops' clock. With a metrics
+registry installed a span observes its duration under its name; with a
+recorder installed it records one event of its name with ``duration_s``.
+Without a capture, a registry or a recorder a ``with`` span costs two
+clock reads, an idle annotation and a ring append (about 1.8 us on a TPU
+v5e host), an after-the-fact span about 0.7 us.
+Like the registry, the span stack is not thread-safe: one Python thread
+drives the instrumented paths.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import json
 import os
 import time
 from collections import deque
-from contextlib import contextmanager, nullcontext
+from contextlib import contextmanager
 from pathlib import Path
 from typing import Any
+
+from repro.obs import metrics
 
 EVENT_LOG_ENV = "REPRO_EVENT_LOG"
 DEFAULT_CAPACITY = 4096
@@ -88,17 +107,6 @@ class FlightRecorder:
         if self.sink is not None:
             self._write_line(ev)
         return ev
-
-    @contextmanager
-    def span(self, kind: str, **data: Any):
-        """Times a ``with`` block and records ONE event on exit with the
-        measured ``duration_s`` (single-event spans keep the sink small;
-        the start instant is recoverable as ``ts - duration_s``)."""
-        t0 = time.monotonic()
-        try:
-            yield self
-        finally:
-            self.record(kind, duration_s=time.monotonic() - t0, **data)
 
     # -- inspection --------------------------------------------------------
     def events(self, kind: str | None = None) -> list[Event]:
@@ -224,17 +232,117 @@ def record(kind: str, **data: Any) -> Event | None:
     return None
 
 
-def span(kind: str, **data: Any):
-    """A span on the active recorder, or a shared no-op when disabled."""
-    if _RECORDER is None:
-        return nullcontext(None)
-    return _RECORDER.span(kind, **data)
-
-
 def crash_dump(path: str | Path | None = None, *, reason: str = "") -> Path | None:
     if _RECORDER is not None:
         return _RECORDER.crash_dump(path, reason=reason)
     return None
+
+
+# -- spans ------------------------------------------------------------------
+
+SPAN_CAPACITY = 16384
+
+
+class Span:
+    """One span; times are ``time.perf_counter_ns()``. :func:`span` returns
+    it open, and ``rid``, ``batch`` and ``data`` (the recorder event's
+    payload) may be set inside the ``with`` block, for what is known only
+    once the work has begun. The ring holds it once it has ended."""
+
+    __slots__ = ("name", "start_ns", "end_ns", "id", "parent", "rid", "batch",
+                 "data", "_annotation")
+
+    def __init__(self, name: str, rid: int | None = None, batch: int | None = None,
+                 data: dict[str, Any] | None = None) -> None:
+        self.name = name
+        self.rid = rid          # request id
+        self.batch = batch      # batch number
+        self.data = data
+        self.parent = None      # id of the span open around this one
+
+    @property
+    def duration_s(self) -> float:
+        return (self.end_ns - self.start_ns) * 1e-9
+
+    def __repr__(self) -> str:
+        return (f"Span({self.name!r}, id={self.id}, parent={self.parent}, "
+                f"rid={self.rid}, batch={self.batch}, "
+                f"duration_s={self.duration_s:.3g})")
+
+    def __enter__(self) -> "Span":
+        self._annotation = _annotation(self.name)
+        self._annotation.__enter__()
+        if _OPEN:
+            self.parent = _OPEN[-1]
+        self.id = sid = next(_SPAN_IDS)
+        _OPEN.append(sid)
+        self.start_ns = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.end_ns = time.perf_counter_ns()
+        _OPEN.pop()
+        self._annotation.__exit__(*exc)
+        self._annotation = None
+        _finish(self)
+        return False
+
+
+_SPANS: deque[Span] = deque(maxlen=SPAN_CAPACITY)
+_SPAN_IDS = itertools.count()
+_OPEN: list[int] = []   # ids of the open ``with`` spans, innermost last
+_spans_dropped = 0
+_annotation_cls = None
+
+
+def _annotation(name: str):
+    global _annotation_cls
+    if _annotation_cls is None:
+        import jax.profiler
+
+        _annotation_cls = jax.profiler.TraceAnnotation
+    return _annotation_cls(name)
+
+
+def _finish(sp: Span) -> None:
+    global _spans_dropped
+    if len(_SPANS) == _SPANS.maxlen:
+        _spans_dropped += 1
+    _SPANS.append(sp)
+    if metrics.current() is not None:
+        metrics.observe(sp.name, sp.duration_s)
+    if _RECORDER is not None:
+        tags = {k: v for k, v in (("rid", sp.rid), ("batch", sp.batch)) if v is not None}
+        _RECORDER.record(sp.name, duration_s=sp.duration_s, **tags, **(sp.data or {}))
+
+
+def span(name: str, *, rid: int | None = None, batch: int | None = None,
+         **data: Any) -> Span:
+    """Times a ``with`` block as a span named ``name``, a child of the
+    innermost open span, under a profiler annotation of the same name.
+    ``data`` goes to the recorder's event only."""
+    return Span(name, rid, batch, data)
+
+
+def record_span(name: str, start_ns: int, end_ns: int, *, rid: int | None = None,
+                batch: int | None = None, **data: Any) -> Span:
+    """Records a span after the fact from two ``time.perf_counter_ns()``
+    stamps, for an interval no ``with`` block brackets (a request's wait in
+    the queue). It has no parent and no profiler annotation."""
+    sp = Span(name, rid, batch, data)
+    sp.start_ns, sp.end_ns, sp.id = start_ns, end_ns, next(_SPAN_IDS)
+    _finish(sp)
+    return sp
+
+
+def spans() -> list[Span]:
+    """A snapshot of the span ring, oldest first."""
+    return list(_SPANS)
+
+
+def spans_dropped() -> int:
+    """How many spans have fallen off the ring since the process started."""
+    return _spans_dropped
 
 
 if os.environ.get(EVENT_LOG_ENV, "").strip():

@@ -16,15 +16,25 @@ mix, so a request whose fields blow up (NaN/Inf, caught by a
 with results identical to unbatched runs. The stress suite injects exactly
 this.
 
-Telemetry mirrors the token server's, workload-neutrally named:
+Telemetry. Every phase of the server is a span (:func:`repro.obs.events
+.span`), recorded always in the span ring and, while a profiler captures,
+on its host plane:
 
-  * ``serve.forecast.queue_latency`` — per-request submit-to-dispatch wait;
-  * ``serve.forecast.member_occupancy`` — members in the last batch /
-    ``max_batch`` (0.0 when idle — same staleness rule as the lane gauge);
-  * ``serve.forecast.steps_per_sec`` / ``serve.forecast.members_per_sec``
-    — batched-step and member throughput over a ``run_until_idle`` drain;
-  * counters ``serve.forecast.{requests_submitted,batches,members,
-    completed,failed}``.
+  * per request: ``serve.forecast.submit`` (rid) and
+    ``serve.forecast.queue_wait`` (rid, batch), from submit to dispatch,
+    recorded after the fact;
+  * per batch: ``serve.forecast.step`` (batch), the parent of
+    ``serve.forecast.admit`` (group pick, cache lookup),
+    ``serve.forecast.stack``, ``serve.forecast.dispatch`` (the cached
+    batched call, until it returns), ``serve.forecast.device_wait``
+    (``block_until_ready``), ``serve.forecast.unstack`` (the per-member
+    slices) and ``serve.forecast.retire`` (health check, stamps, events).
+
+With a metrics registry installed each span is also a timer of its name,
+beside the gauge ``serve.forecast.member_occupancy`` (members in the last
+batch / ``max_batch``; 0.0 when idle, the lane gauge's staleness rule) and
+the counters ``serve.forecast.{requests_submitted,batches,members,
+completed,failed}``.
 """
 
 from __future__ import annotations
@@ -65,6 +75,7 @@ class ForecastRequest:
     done_ts: float | None = None
     queue_latency_s: float | None = None
     items_per_sec: float | None = None
+    _submitted_ns: int = dataclasses.field(init=False, repr=False, default=0)
 
     @property
     def failed(self) -> bool:
@@ -96,6 +107,11 @@ class ForecastServer:
     that trips the monitor retires with ``error`` set while its batchmates
     complete normally — the vmap path computes members independently, so a
     blown-up member cannot contaminate the others.
+
+    Each ``step()`` is one ``serve.forecast.step`` span over its six phase
+    spans, and each request adds a ``submit`` and a ``queue_wait`` span
+    (module docstring): ``repro.obs.events.spans()`` then says where the
+    server's time went, batch by batch, with nothing installed.
     """
 
     def __init__(
@@ -131,6 +147,15 @@ class ForecastServer:
         """Enqueue one forecast. ``fields`` is a ``{input: (D, R, C)}``
         mapping (or the bare array for single-input programs); shapes and
         dtypes join the admission key, so mixed grids never co-batch."""
+        with events.span("serve.forecast.submit") as sp:
+            req = self._enqueue(program, fields)
+            # The span's recorder event is the request's submit event.
+            sp.rid = req.rid
+            sp.data.update(program=program.name, grid=list(req.group_key.grid),
+                           k=program.steps)
+        return req.rid
+
+    def _enqueue(self, program, fields: Array | Mapping[str, Array]) -> ForecastRequest:
         if not isinstance(fields, Mapping):
             if len(program.inputs) != 1:
                 raise ValueError(
@@ -167,14 +192,11 @@ class ForecastServer:
             mesh_shape=self.mesh_shape,
             batch=None,
         )
-        req.submitted_ts = time.perf_counter()
+        req._submitted_ns = time.perf_counter_ns()
+        req.submitted_ts = req._submitted_ns * 1e-9
         self._queue.append(req)
         metrics.inc("serve.forecast.requests_submitted")
-        events.record(
-            "serve.forecast.submit", rid=rid, program=program.name,
-            grid=list(grid), k=program.steps,
-        )
-        return rid
+        return req
 
     def pending(self) -> int:
         return len(self._queue)
@@ -199,46 +221,59 @@ class ForecastServer:
         if not self._queue:
             metrics.set_gauge("serve.forecast.member_occupancy", 0.0)
             return False
-        group = self._admit_group()
-        now = time.perf_counter()
-        for req in group:
-            req.dispatch_ts = now
-            if req.submitted_ts is not None:
-                req.queue_latency_s = now - req.submitted_ts
-                metrics.observe("serve.forecast.queue_latency", req.queue_latency_s)
-        key = group[0].group_key
-        program = group[0].program
+        batch = self.stats["batches"]
+        with events.span("serve.forecast.step", batch=batch):
+            with events.span("serve.forecast.admit", batch=batch):
+                group = self._admit_group()
+                now_ns = time.perf_counter_ns()
+                for req in group:
+                    req.dispatch_ts = now_ns * 1e-9
+                    req.queue_latency_s = (now_ns - req._submitted_ns) * 1e-9
+                    events.record_span("serve.forecast.queue_wait", req._submitted_ns,
+                                       now_ns, rid=req.rid, batch=batch)
+                key = group[0].group_key
+                program = group[0].program
+                n = len(group)
+                metrics.set_gauge("serve.forecast.member_occupancy", n / self.max_batch)
+                fn = self.cache.get(
+                    program,
+                    grid=key.grid,
+                    dtype=key.dtype,
+                    backend=key.backend,
+                    mesh_shape=key.mesh,
+                    batch=n,
+                    **self.lower_kwargs,
+                )
+            with events.span("serve.forecast.stack", batch=batch):
+                batched = {
+                    f: jnp.stack([req.fields[f] for req in group])
+                    for f in program.inputs
+                }
+            with events.span("serve.forecast.dispatch", batch=batch):
+                out = fn(batched)
+            with events.span("serve.forecast.device_wait", batch=batch):
+                out = jax.block_until_ready(out)
+            with events.span("serve.forecast.unstack", batch=batch):
+                members = [
+                    {f: v[i] for f, v in out.items()} if isinstance(out, Mapping)
+                    else out[i]
+                    for i in range(n)
+                ]
+            with events.span("serve.forecast.retire", batch=batch):
+                self._retire(group, members, batch)
+        return True
+
+    def _retire(self, group: list[ForecastRequest], members: list, batch: int) -> None:
+        """Health-checks each member's result and stamps its request done."""
         n = len(group)
-        metrics.set_gauge("serve.forecast.member_occupancy", n / self.max_batch)
-        fn = self.cache.get(
-            program,
-            grid=key.grid,
-            dtype=key.dtype,
-            backend=key.backend,
-            mesh_shape=key.mesh,
-            batch=n,
-            **self.lower_kwargs,
-        )
-        batched = {
-            f: jnp.stack([req.fields[f] for req in group])
-            for f in program.inputs
-        }
-        with metrics.timer("serve.forecast.step"):
-            out = fn(batched)
-            out = jax.block_until_ready(out)
         self.stats["batches"] += 1
         self.stats["members"] += n
         metrics.inc("serve.forecast.batches")
         metrics.inc("serve.forecast.members", n)
         done = time.perf_counter()
-        for i, req in enumerate(group):
-            member = (
-                {f: v[i] for f, v in out.items()}
-                if isinstance(out, Mapping)
-                else out[i]
-            )
+        for req, member in zip(group, members):
             req.done_ts = done
-            if req.dispatch_ts is not None and done > req.dispatch_ts:
+            if done > req.dispatch_ts:
                 req.items_per_sec = 1.0 / (done - req.dispatch_ts)
             try:
                 self._check_member(req, member)
@@ -248,7 +283,7 @@ class ForecastServer:
                 metrics.inc("serve.forecast.failed")
                 events.record(
                     "serve.forecast.fail", rid=req.rid,
-                    program=program.name, field=err.field,
+                    program=req.program.name, field=err.field,
                 )
             else:
                 req.result = member
@@ -257,11 +292,10 @@ class ForecastServer:
             req.done = True
             self.completed.append(req)
             events.record(
-                "serve.forecast.retire", rid=req.rid, batch=n,
+                "serve.forecast.retire", rid=req.rid, batch=batch, members=n,
                 failed=req.failed, queue_latency_s=req.queue_latency_s,
                 items_per_sec=req.items_per_sec,
             )
-        return True
 
     def _check_member(self, req: ForecastRequest, member) -> None:
         """Force-check every output field of ONE member's result against
@@ -277,24 +311,11 @@ class ForecastServer:
 
     def run_until_idle(self, max_steps: int = 10_000) -> list[ForecastRequest]:
         """Drain the queue; returns the requests retired by THIS drain (in
-        retirement order) and stamps the throughput gauges."""
+        retirement order)."""
         start = len(self.completed)
-        steps0 = self.stats["batches"]
-        members0 = self.stats["members"]
-        t0 = time.perf_counter()
         for _ in range(max_steps):
             if not self.step():
                 break
-        elapsed = time.perf_counter() - t0
-        if elapsed > 0:
-            metrics.set_gauge(
-                "serve.forecast.steps_per_sec",
-                (self.stats["batches"] - steps0) / elapsed,
-            )
-            metrics.set_gauge(
-                "serve.forecast.members_per_sec",
-                (self.stats["members"] - members0) / elapsed,
-            )
         return self.completed[start:]
 
     def metrics_text(self) -> str:

@@ -7,7 +7,10 @@ run, the CompoundStencil policy wrappers, the planners, and the lowering
 argument validation (including the 2-D mesh arguments).
 """
 
+import re
+
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -21,9 +24,11 @@ from repro.ir import (
     StencilProgram,
     affine,
     hdiff_program,
+    jacobi1d_program,
     lower_pallas,
     lower_reference,
     lower_sharded,
+    shallow_water_program,
 )
 from repro.ir import plan_partition as plan_partition_2d
 from repro.launch.mesh import make_mesh
@@ -171,6 +176,40 @@ def test_lower_pallas_rejects_bad_inputs():
         fn2({"a": _grid(2, 8, 8)})
     with pytest.raises(ValueError, match="share one grid"):
         fn2({"a": _grid(2, 8, 8), "b": _grid(2, 8, 16)})
+
+
+# --- names in traces -----------------------------------------------------------
+
+
+def _pallas_eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for v in eqn.params.values():
+            sub = getattr(v, "jaxpr", v)
+            if hasattr(sub, "eqns"):
+                yield from _pallas_eqns(sub)
+
+
+@pytest.mark.parametrize("prog,shape", [
+    (hdiff_program(), (1, 36, 128)),                  # one output, padded rows
+    (shallow_water_program(), (1, 16, 24)),           # coupled outputs
+    (jacobi1d_program(), (8, 32)),                    # the 1-D kernel
+], ids=["hdiff", "shallow_water", "jacobi1d"])
+def test_pallas_call_carries_the_program_name(prog, shape):
+    fn = lower_pallas(prog, interpret=True, vmem_budget=32768)
+    x = _grid(*shape)
+    args = {f: x for f in prog.inputs} if len(prog.inputs) > 1 else x
+    jaxpr = jax.make_jaxpr(lambda a: fn(a))(args)
+    names = [e.params["name"] for e in _pallas_eqns(jaxpr.jaxpr)]
+    assert names == [f"ir_{prog.name}"]
+
+
+def test_pad_and_crop_sit_under_program_scopes():
+    fn = lower_pallas(hdiff_program(), interpret=True, vmem_budget=32768)
+    text = jax.jit(lambda a: fn(a)).lower(_grid(1, 36, 128)).compile().as_text()
+    scopes = set(re.findall(r'op_name="[^"]*/(ir/hdiff/(?:pad|crop))/', text))
+    assert scopes == {"ir/hdiff/pad", "ir/hdiff/crop"}
 
 
 def test_lower_sharded_validates_axes_and_shapes():

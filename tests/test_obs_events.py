@@ -7,7 +7,8 @@ Covers the event-log contracts:
     ordered by a recorder-local sequence number;
   * with a sink every event lands in the JSONL file as recorded, behind a
     ``meta`` header line carrying the runtime stamp;
-  * ``span`` records one event with the measured duration;
+  * a span records one event with the measured duration on the active
+    recorder (the span primitive itself: ``test_obs_spans.py``);
   * ``crash_dump`` flushes the whole ring (+ reason + metadata) to a JSON
     document, defaulting next to the sink;
   * the module switchboard mirrors ``repro.obs.metrics`` exactly —
@@ -77,9 +78,9 @@ def test_capacity_validation():
 
 
 def test_span_records_one_event_with_duration():
-    rec = FlightRecorder()
-    with rec.span("phase", label="x"):
-        pass
+    with events.using() as rec:
+        with events.span("phase", label="x"):
+            pass
     (ev,) = rec.events("phase")
     assert ev.data["label"] == "x"
     assert ev.data["duration_s"] >= 0.0

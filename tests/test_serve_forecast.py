@@ -8,8 +8,9 @@ The serving claims under test:
     arrival order — ALL complete, each batch stays homogeneous, and every
     completed result matches an unbatched oracle (same backend, bit-exact;
     reference oracle, 1e-6);
-  * per-request telemetry (queue latency, items/sec) and the server gauges
-    (member occupancy incl. idle reset, steps/sec) are stamped;
+  * per-request telemetry (queue latency, items/sec) and the member
+    occupancy gauge (incl. idle reset) are stamped, and every batch is a
+    ``serve.forecast.step`` span over its six phase spans;
   * a NaN-injected request (caught post-step by a HealthMonitor) fails
     ALONE: its batchmates complete with results identical to a run where
     the poisoned request never existed;
@@ -35,6 +36,7 @@ from repro.obs.health import HealthMonitor, NumericsError
 from repro.serve import CompileCache, ForecastServer, compile_key
 
 SEED = 99
+PHASES = ("admit", "stack", "dispatch", "device_wait", "unstack", "retire")
 
 
 @pytest.fixture(autouse=True)
@@ -181,17 +183,60 @@ def test_telemetry_stamped_per_request_and_server():
         assert r.queue_latency_s is not None and r.queue_latency_s >= 0
         assert r.items_per_sec is not None and r.items_per_sec > 0
     snap = metrics.current().snapshot()
-    assert snap["gauges"]["serve.forecast.steps_per_sec"] > 0
-    assert snap["gauges"]["serve.forecast.members_per_sec"] > 0
+    # Every batch and every phase of it is a span, a timer of its name.
+    batches = srv.stats["batches"]
+    for phase in PHASES + ("step",):
+        assert snap["timers"][f"serve.forecast.{phase}"]["count"] == batches
+    assert snap["timers"]["serve.forecast.step"]["total_s"] > 0
     # Occupancy resets to idle after the drain (the staleness rule).
     assert snap["gauges"]["serve.forecast.member_occupancy"] == 0.0
     assert snap["counters"]["serve.forecast.requests_submitted"] == len(done)
     assert snap["counters"]["serve.forecast.completed"] == len(done)
-    assert snap["timers"]["serve.forecast.queue_latency"]["count"] == len(done)
-    # Retire events carry the per-request telemetry.
+    assert snap["timers"]["serve.forecast.queue_wait"]["count"] == len(done)
+    assert snap["timers"]["serve.forecast.submit"]["count"] == len(done)
+    # Retire events carry the per-request telemetry; the retire span adds
+    # one event per batch.
     retires = events.current().events("serve.forecast.retire")
-    assert len(retires) == len(done)
-    assert all(e.data["items_per_sec"] > 0 for e in retires)
+    per_request = [e for e in retires if "rid" in e.data]
+    assert len(per_request) == len(done)
+    assert all(e.data["items_per_sec"] > 0 for e in per_request)
+    assert len(retires) - len(per_request) == batches
+    submits = events.current().events("serve.forecast.submit")
+    assert sorted(e.data["rid"] for e in submits) == sorted(r.rid for r in done)
+
+
+def test_drain_records_a_step_span_per_batch_with_its_phases():
+    srv = ForecastServer(max_batch=2)
+    prog = hdiff_program()
+    first = max((s.id for s in events.spans()), default=-1)
+    rids = [srv.submit(prog, _noise((2, 16, 16), SEED + i)) for i in range(5)]
+    done = srv.run_until_idle()
+    assert srv.stats["batches"] == 3
+    new = [s for s in events.spans() if s.id > first]
+    steps = [s for s in new if s.name == "serve.forecast.step"]
+    assert [s.batch for s in steps] == [0, 1, 2]
+    for step in steps:
+        kids = [s for s in new if s.parent == step.id]
+        assert [k.name for k in kids] == [f"serve.forecast.{p}" for p in PHASES]
+        assert all(k.batch == step.batch for k in kids)
+        assert step.start_ns <= kids[0].start_ns
+        assert all(a.end_ns <= b.start_ns for a, b in zip(kids, kids[1:]))
+        assert kids[-1].end_ns <= step.end_ns
+    # One queue wait per request: submit to dispatch, its rid and batch.
+    waits = {s.rid: s for s in new if s.name == "serve.forecast.queue_wait"}
+    assert sorted(waits) == sorted(rids)
+    submits = [s for s in new if s.name == "serve.forecast.submit"]
+    assert sorted(s.rid for s in submits) == sorted(rids)
+    by_batch = {s.batch: s for s in steps}
+    for r in done:
+        w = waits[r.rid]
+        assert w.duration_s == r.queue_latency_s
+        assert w.start_ns * 1e-9 == pytest.approx(r.submitted_ts, abs=1e-9)
+        assert w.end_ns * 1e-9 == pytest.approx(r.dispatch_ts, abs=1e-9)
+        admit = next(s for s in new if s.name == "serve.forecast.admit"
+                     and s.parent == by_batch[w.batch].id)
+        assert admit.start_ns <= w.end_ns <= admit.end_ns
+    assert [waits[r].batch for r in rids] == [0, 0, 1, 1, 2]
 
 
 def test_member_occupancy_gauge_tracks_last_batch():
