@@ -83,21 +83,27 @@ def compile_key(
 
 @dataclasses.dataclass
 class CacheEntry:
-    """One cached lowered callable + its trace-count probe state."""
+    """One cached lowered callable + its trace-count probe state.
+
+    ``marshal`` is free for the caller to hang a callable built around
+    ``fn`` on (``ForecastServer`` keeps its one-call batch wrapper there),
+    so that it lives and is evicted with the entry."""
 
     key: CompileKey
     fn: Callable
     program_name: str
     traces: int = 0
     hits: int = 0
+    marshal: Callable | None = None
 
 
 class CompileCache:
     """LRU cache of lowered (and trace-probed) program callables.
 
-    ``get`` is the whole API surface the engine uses: key the request,
+    ``get`` is the whole API surface the engines use: key the request,
     return the cached callable or build-and-insert it, evicting the least
-    recently used entry past ``capacity``. Like the metrics registry it is
+    recently used entry past ``capacity``; ``get_entry`` is the same with
+    the whole :class:`CacheEntry` returned. Like the metrics registry it is
     deliberately not thread-safe — one Python scheduler drives it.
 
     ``builder(program, key, **lower_kwargs) -> callable`` constructs a
@@ -174,6 +180,23 @@ class CompileCache:
         **lower_kwargs,
     ) -> Callable:
         """The lowered callable for one request shape (cached)."""
+        return self.get_entry(
+            program, grid=grid, dtype=dtype, backend=backend,
+            mesh_shape=mesh_shape, batch=batch, **lower_kwargs,
+        ).fn
+
+    def get_entry(
+        self,
+        program,
+        *,
+        grid: tuple[int, ...],
+        dtype: Any = np.float32,
+        backend: str = "reference",
+        mesh_shape: tuple[int, int] | None = None,
+        batch: int | None = None,
+        **lower_kwargs,
+    ) -> CacheEntry:
+        """:meth:`get`, returning the entry rather than its callable."""
         key = compile_key(
             program, grid=grid, dtype=dtype, backend=backend,
             mesh_shape=mesh_shape, batch=batch,
@@ -184,7 +207,7 @@ class CompileCache:
             entry.hits += 1
             self.hits += 1
             metrics.inc("cache.hits")
-            return entry.fn
+            return entry
         self.misses += 1
         metrics.inc("cache.misses")
         built = self.builder(program, key, **lower_kwargs)
@@ -204,7 +227,7 @@ class CompileCache:
                 "cache.evict", program=old.program_name,
                 backend=old_key.backend, k=old_key.k, batch=old_key.batch,
             )
-        return entry.fn
+        return entry
 
 
 def _with_trace_probe(fn: Callable, entry: CacheEntry) -> Callable:

@@ -25,16 +25,22 @@ on its host plane:
     recorded after the fact;
   * per batch: ``serve.forecast.step`` (batch), the parent of
     ``serve.forecast.admit`` (group pick, cache lookup),
-    ``serve.forecast.stack``, ``serve.forecast.dispatch`` (the cached
-    batched call, until it returns), ``serve.forecast.device_wait``
-    (``block_until_ready``), ``serve.forecast.unstack`` (the per-member
-    slices) and ``serve.forecast.retire`` (health check, stamps, events).
+    ``serve.forecast.stack`` (gathering the members' fields into the
+    call's arguments), ``serve.forecast.dispatch`` (the batch's one
+    compiled call — member stack, batched kernel, per-member slices —
+    until it returns), ``serve.forecast.device_wait``
+    (``block_until_ready`` on the per-member results),
+    ``serve.forecast.unstack`` (handing the per-member results on) and
+    ``serve.forecast.retire`` (health check, stamps, events).
 
 With a metrics registry installed each span is also a timer of its name,
 beside the gauge ``serve.forecast.member_occupancy`` (members in the last
 batch / ``max_batch``; 0.0 when idle, the lane gauge's staleness rule) and
 the counters ``serve.forecast.{requests_submitted,batches,members,
-completed,failed}``.
+completed,failed}``. ``serve.forecast.marshal_builds`` counts the one-call
+wrappers built, one per cache entry (so one per batch size of a program
+and grid), each with a ``serve.forecast.marshal_build`` event naming its
+batch size.
 """
 
 from __future__ import annotations
@@ -90,14 +96,39 @@ class ForecastRequest:
     _group_key: CompileKey = dataclasses.field(init=False, repr=False, default=None)
 
 
+def _marshal(fn: Callable, inputs: tuple[str, ...]) -> Callable:
+    """One batch as one jitted call around a cache entry's batched ``fn``.
+
+    The call takes the members' ``{input: (D, R, C)}`` mappings, stacks
+    each input along a fresh member axis, runs ``fn`` on the stacked
+    mapping and slices the batched output back into one result per
+    member: an array, or ``{field: array}`` for multi-output programs.
+    Stack, kernel and slices are one program, so a batch is one dispatch
+    with no host round trip between them; the slices are exact, so each
+    member's result is bit-identical to ``fn``'s ``out[i]``. The batch
+    size is the tuple's length, so one wrapper per cache entry traces once.
+    Nothing is donated: a request's fields stay valid after its step.
+    """
+
+    def call(members: tuple[Mapping[str, Array], ...]):
+        out = fn({f: jnp.stack([m[f] for m in members]) for f in inputs})
+        if isinstance(out, Mapping):
+            return tuple({f: v[i] for f, v in out.items()} for i in range(len(members)))
+        return tuple(out[i] for i in range(len(members)))
+
+    return jax.jit(call)
+
+
 class ForecastServer:
     """Admission control + vmap-batched execution over a compile cache.
 
     One ``step()`` = one batched forecast: pop the oldest pending request,
     sweep the queue for up to ``max_batch - 1`` more requests with the SAME
     group key (FIFO within the group; incompatible requests keep their
-    place for a later step), stack their fields along a fresh member axis,
-    run the cached batched lowering once, and unstack per-member results.
+    place for a later step), and run them as one compiled call that stacks
+    their fields along a fresh member axis, runs the cached batched
+    lowering and slices out per-member results (:func:`_marshal`, held on
+    the cache entry and evicted with it).
     Heterogeneous tenants therefore interleave safely: each step is
     homogeneous, and no request is starved because group sweeps always
     start from the queue head.
@@ -235,7 +266,7 @@ class ForecastServer:
                 program = group[0].program
                 n = len(group)
                 metrics.set_gauge("serve.forecast.member_occupancy", n / self.max_batch)
-                fn = self.cache.get(
+                entry = self.cache.get_entry(
                     program,
                     grid=key.grid,
                     dtype=key.dtype,
@@ -244,21 +275,19 @@ class ForecastServer:
                     batch=n,
                     **self.lower_kwargs,
                 )
+                if entry.marshal is None:
+                    entry.marshal = _marshal(entry.fn, program.inputs)
+                    metrics.inc("serve.forecast.marshal_builds")
+                    events.record("serve.forecast.marshal_build",
+                                  program=program.name, batch=n)
             with events.span("serve.forecast.stack", batch=batch):
-                batched = {
-                    f: jnp.stack([req.fields[f] for req in group])
-                    for f in program.inputs
-                }
+                args = tuple(req.fields for req in group)
             with events.span("serve.forecast.dispatch", batch=batch):
-                out = fn(batched)
+                out = entry.marshal(args)
             with events.span("serve.forecast.device_wait", batch=batch):
                 out = jax.block_until_ready(out)
             with events.span("serve.forecast.unstack", batch=batch):
-                members = [
-                    {f: v[i] for f, v in out.items()} if isinstance(out, Mapping)
-                    else out[i]
-                    for i in range(n)
-                ]
+                members = list(out)
             with events.span("serve.forecast.retire", batch=batch):
                 self._retire(group, members, batch)
         return True

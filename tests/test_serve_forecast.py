@@ -15,12 +15,22 @@ The serving claims under test:
     ALONE: its batchmates complete with results identical to a run where
     the poisoned request never existed;
   * warm serving never re-traces: a second wave of same-shaped requests is
-    all cache hits with zero new jax traces (the acceptance invariant).
+    all cache hits with zero new jax traces (the acceptance invariant);
+  * a batch is one compiled call (member stack, batched lowering,
+    per-member slices), built once per cache entry and bit-identical to
+    slicing the cache entry's own batched output, whose ``fn`` still
+    lowers from a stacked mapping.
 """
+
+import os
+import subprocess
+import sys
+from pathlib import Path
 
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from conformance import assert_close, assert_equal, to_host
@@ -35,6 +45,7 @@ from repro.obs import events, metrics
 from repro.obs.health import HealthMonitor, NumericsError
 from repro.serve import CompileCache, ForecastServer, compile_key
 
+REPO = Path(__file__).resolve().parents[1]
 SEED = 99
 PHASES = ("admit", "stack", "dispatch", "device_wait", "unstack", "retire")
 
@@ -222,6 +233,12 @@ def test_drain_records_a_step_span_per_batch_with_its_phases():
         assert step.start_ns <= kids[0].start_ns
         assert all(a.end_ns <= b.start_ns for a, b in zip(kids, kids[1:]))
         assert kids[-1].end_ns <= step.end_ns
+        # The phases are leaves: the batch's call opens no span of its own.
+        assert not [s for s in new if s.parent in {k.id for k in kids}]
+    # Batches of 2, 2, 1: one call wrapper per batch size, built on the
+    # first batch of that size.
+    builds = events.current().events("serve.forecast.marshal_build")
+    assert [e.data["batch"] for e in builds] == [2, 1]
     # One queue wait per request: submit to dispatch, its rid and batch.
     waits = {s.rid: s for s in new if s.name == "serve.forecast.queue_wait"}
     assert sorted(waits) == sorted(rids)
@@ -338,3 +355,126 @@ def test_submit_validation():
         srv.submit(hdiff_program(), jnp.zeros((8, 8)))
     with pytest.raises(ValueError, match="max_batch"):
         ForecastServer(max_batch=0)
+
+
+# -- the one-call batch path ----------------------------------------------------
+
+
+def _serve(srv, prog, fields):
+    rids = [srv.submit(prog, f) for f in fields]
+    done = {r.rid: r for r in srv.run_until_idle()}
+    return [done[rid] for rid in rids]
+
+
+def _stacked(prog, fields):
+    return {
+        f: jnp.stack([m[f] if isinstance(m, dict) else m for m in fields])
+        for f in prog.inputs
+    }
+
+
+@pytest.mark.parametrize("n", [1, 3, 8])
+@pytest.mark.parametrize("name", ["hdiff", "shallow_water"])
+def test_one_call_batch_bit_matches_slices_of_the_batched_output(name, n):
+    """Each member's result from the one-call path equals ``out[i]`` of the
+    cache entry's own batched call on the stacked fields, bit for bit —
+    for a single-output program and for a multi-output one."""
+    prog, grid = {"hdiff": (hdiff_program(), (2, 16, 16)),
+                  "shallow_water": (shallow_water_program(), (2, 12, 12))}[name]
+    make = _noise if name == "hdiff" else _sw_fields
+    fields = [make(grid, SEED + i) for i in range(n)]
+    srv = ForecastServer(max_batch=8)
+    done = _serve(srv, prog, fields)
+    assert srv.stats["batches"] == 1
+    entry = srv.cache.lookup(compile_key(prog, grid=grid, batch=n))
+    out = entry.fn(_stacked(prog, fields))
+    for i, r in enumerate(done):
+        want = {f: v[i] for f, v in out.items()} if isinstance(out, dict) else out[i]
+        assert isinstance(r.result, dict) == isinstance(want, dict)
+        assert_equal(to_host(r.result), to_host(want), err_msg=f"member {i} of {n}")
+    assert entry.traces == 1  # the wrapper's trace and the direct call share one
+
+
+def test_marshal_builds_once_per_batch_size():
+    """One wrapper per cache entry: batches of 4, 4, 3 build two, and a
+    second drain of the same sizes builds none."""
+    srv = ForecastServer(max_batch=4)
+    prog = hdiff_program()
+    fields = [_noise((2, 16, 16), SEED + i) for i in range(11)]
+    _serve(srv, prog, fields)
+    counters = metrics.current().snapshot()["counters"]
+    assert counters["serve.forecast.marshal_builds"] == 2
+    _serve(srv, prog, fields[:7])
+    counters = metrics.current().snapshot()["counters"]
+    assert counters["serve.forecast.marshal_builds"] == 2
+    assert srv.stats["batches"] == 5
+    builds = events.current().events("serve.forecast.marshal_build")
+    assert [(e.data["program"], e.data["batch"]) for e in builds] == [
+        ("hdiff", 4), ("hdiff", 3)]
+    for n in (4, 3):
+        assert srv.cache.lookup(compile_key(prog, grid=(2, 16, 16), batch=n)).marshal
+
+
+def test_marshal_rebuilds_after_eviction():
+    """The wrapper lives on the cache entry: evicting the entry drops it,
+    and the next batch of that size builds it again (one more build)."""
+    srv = ForecastServer(max_batch=2, cache_capacity=1)
+    prog = hdiff_program()
+    fields = [_noise((2, 16, 16), SEED + i) for i in range(3)]
+    _serve(srv, prog, fields)            # batch of 2, then of 1: evicts 2's
+    key2 = compile_key(prog, grid=(2, 16, 16), batch=2)
+    assert key2 not in srv.cache
+    _serve(srv, prog, fields[:2])        # batch of 2 again: a miss, rebuilt
+    counters = metrics.current().snapshot()["counters"]
+    assert counters["serve.forecast.marshal_builds"] == 3
+    assert srv.cache.stats()["misses"] == 3
+    assert srv.cache.lookup(key2).marshal is not None
+    assert srv.cache.lookup(key2).traces == 1
+
+
+def test_cache_entry_fn_still_lowers_from_a_stacked_mapping():
+    """The cache entry's ``fn`` keeps its contract: it takes the stacked
+    ``{field: (n, D, R, C)}`` mapping, lowers and compiles from it, and its
+    batched output slices to the served results."""
+    prog = hdiff_program()
+    fields = [_noise((2, 16, 16), SEED + i) for i in range(3)]
+    srv = ForecastServer(max_batch=4)
+    done = _serve(srv, prog, fields)
+    entry = srv.cache.lookup(compile_key(prog, grid=(2, 16, 16), batch=3))
+    compiled = entry.fn.lower(_stacked(prog, fields)).compile()
+    out = compiled(_stacked(prog, fields))
+    for i, r in enumerate(done):
+        assert_equal(to_host(r.result), to_host(out[i]), err_msg=f"member {i}")
+    assert entry.traces == 1
+
+
+def test_fed_back_results_reuse_the_compiled_call():
+    """Closed loop, as an ensemble client runs it: members sliced eagerly
+    from one seeded array, then the server's own results fed back. Every
+    timestep hits the same compiled signature of each batch size's call."""
+    init = jax.jit(lambda k: jax.random.normal(k, (5, 2, 16, 16)))(jax.random.key(SEED))
+    states = [init[i] for i in range(5)]
+    srv = ForecastServer(max_batch=3)
+    prog = hdiff_program()
+    for _ in range(3):
+        states = [r.result for r in _serve(srv, prog, states)]
+    entries = [srv.cache.lookup(k) for k in srv.cache.keys()]
+    assert sorted(e.key.batch for e in entries) == [2, 3]
+    assert [e.marshal._cache_size() for e in entries] == [1, 1]
+    assert srv.cache.total_traces() == 2
+
+
+@pytest.mark.multidev
+def test_served_mesh_results_keep_member_sharding():
+    """On a 2x2 mesh the one-call path's per-member results bit-match the
+    batched output's slices and keep its (rows, cols) sharding."""
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["PYTHONPATH"] = str(REPO / "src")
+    env["JAX_PLATFORMS"] = "cpu"
+    proc = subprocess.run(
+        [sys.executable, str(REPO / "tests" / "multidev" / "_served_check.py")],
+        capture_output=True, text=True, env=env, timeout=600,
+    )
+    assert proc.returncode == 0, f"STDOUT:\n{proc.stdout}\nSTDERR:\n{proc.stderr}"
+    assert "ALL_OK" in proc.stdout
