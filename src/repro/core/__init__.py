@@ -2,6 +2,7 @@
 
 Public API:
   hdiff, hdiff_simple, hdiff_staged       -- the COSMO horizontal-diffusion kernel
+  hdiff_fields                            -- hdiff of several fields, one coefficient each
   elementary stencils (jacobi1d, ...)     -- §3.5 benchmark suite
   CompoundStencil / make_hdiff_compound   -- staged/fused execution policies
   plan_partition                          -- B-block-style partition planner
@@ -31,6 +32,7 @@ from repro.core.hdiff import (
     HDIFF_SPEC,
     hdiff,
     hdiff_algorithmic_bytes,
+    hdiff_fields,
     hdiff_flops,
     hdiff_min_bytes,
     hdiff_simple,

@@ -32,6 +32,8 @@ single-core / CPU-baseline analogue).
 
 from __future__ import annotations
 
+from typing import Mapping
+
 import jax
 import jax.numpy as jnp
 
@@ -136,6 +138,16 @@ def hdiff_simple(psi: Array, coeff: Array | float = 0.025) -> Array:
     up to the constant passthrough of the boundary."""
     interior = _hdiff_interior(psi, coeff, limit=False)
     return psi.at[..., HALO:-HALO, HALO:-HALO].set(interior.astype(psi.dtype))
+
+
+def hdiff_fields(
+    fields: Mapping[str, Array], coeffs: Mapping[str, float], *, limit: bool = True
+) -> dict[str, Array]:
+    """Each field diffused alone with its own coefficient: ``{f:
+    hdiff(fields[f], coeffs[f])}`` (``hdiff_simple`` when ``limit`` is
+    False). The plain reference of ``repro.ir.hdiff_fields_program``."""
+    one = hdiff if limit else hdiff_simple
+    return {f: one(x, coeffs[f]) for f, x in fields.items()}
 
 
 def hdiff_staged(psi: Array, coeff: Array | float = 0.025, *, limit: bool = True) -> Array:

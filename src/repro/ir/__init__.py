@@ -64,6 +64,7 @@ from repro.ir.programs import (
     MULTIOUTPUT_PROGRAMS,
     advection_diffusion_program,
     hdiff_coupled_program,
+    hdiff_fields_program,
     hdiff_multistep_program,
     hdiff_program,
     jacobi1d_program,

@@ -18,14 +18,16 @@ derived per field and summed; the cross-backend conformance matrix
 timesteps): ``shallow_water_program`` evolves {u, v, h} together through
 the gravity-wave coupling, ``advection_diffusion_program`` evolves {c, u}
 over a shared velocity field — several ``outputs`` per sweep, one merged
-halo exchange, same conformance coverage.
+halo exchange, same conformance coverage. ``hdiff_fields_program`` is the
+uncoupled case: one hdiff chain per diffused field, one fused kernel.
 """
 
 from __future__ import annotations
 
-from typing import Callable
+import dataclasses
+from typing import Callable, Sequence
 
-from repro.ir.graph import StencilProgram, repeat
+from repro.ir.graph import Read, StencilProgram, repeat
 from repro.ir.ops import affine, flux, product, scaled_residual, weighted_residual
 
 # Tap orders deliberately mirror the hand-written kernels' evaluation order
@@ -54,6 +56,37 @@ def hdiff_program(coeff: float = 0.025, *, limit: bool = True) -> StencilProgram
         ),
     ]
     return StencilProgram("hdiff" if limit else "hdiff_simple", ["psi"], ops)
+
+
+def hdiff_fields_program(
+    fields: Sequence[str], coeffs: Sequence[float], *, limit: bool = True
+) -> StencilProgram:
+    """One hdiff chain per field, each with its own coefficient, as one
+    multi-output program (``outputs={field: ...}``).
+
+    COSMO diffuses several prognostic fields (wind components, vertical
+    velocity, temperature, pressure perturbation) each step; here they are
+    one unit of work, so every backend runs them as one fused kernel. The
+    chains share nothing: field ``f``'s ops are ``hdiff_program``'s with
+    ``psi`` read as ``f`` and each op name suffixed ``_f``.
+    """
+    fields, coeffs = tuple(fields), tuple(coeffs)
+    if len(fields) != len(coeffs):
+        raise ValueError(f"{len(fields)} fields but {len(coeffs)} coefficients")
+    ops, outputs = [], {}
+    for f, c in zip(fields, coeffs):
+        base = hdiff_program(c, limit=limit)
+        rename = {base.passthrough: f, **{op.name: f"{op.name}_{f}" for op in base.ops}}
+        ops += [
+            dataclasses.replace(
+                op,
+                name=rename[op.name],
+                reads=tuple(Read(rename[r.field], r.offset) for r in op.reads),
+            )
+            for op in base.ops
+        ]
+        outputs[f] = rename[base.output]
+    return StencilProgram("hdiff_fields", fields, ops, outputs=outputs)
 
 
 def hdiff_multistep_program(

@@ -40,7 +40,8 @@ the counters ``serve.forecast.{requests_submitted,batches,members,
 completed,failed}``. ``serve.forecast.marshal_builds`` counts the one-call
 wrappers built, one per cache entry (so one per batch size of a program
 and grid), each with a ``serve.forecast.marshal_build`` event naming its
-batch size.
+batch size. ``serve.forecast.field_bytes`` (also ``stats["field_bytes"]``)
+counts the logical bytes of the fields each batch reads and writes.
 """
 
 from __future__ import annotations
@@ -167,7 +168,8 @@ class ForecastServer:
         self._queue: list[ForecastRequest] = []
         self._next_rid = 0
         self.completed: list[ForecastRequest] = []
-        self.stats = {"batches": 0, "members": 0, "completed": 0, "failed": 0}
+        self.stats = {"batches": 0, "members": 0, "completed": 0, "failed": 0,
+                      "field_bytes": 0}
 
     # -- admission ---------------------------------------------------------
     def submit(
@@ -295,10 +297,14 @@ class ForecastServer:
     def _retire(self, group: list[ForecastRequest], members: list, batch: int) -> None:
         """Health-checks each member's result and stamps its request done."""
         n = len(group)
+        field_bytes = sum(a.nbytes for req in group for a in req.fields.values())
+        field_bytes += sum(a.nbytes for a in jax.tree.leaves(members))
         self.stats["batches"] += 1
         self.stats["members"] += n
+        self.stats["field_bytes"] += field_bytes
         metrics.inc("serve.forecast.batches")
         metrics.inc("serve.forecast.members", n)
+        metrics.inc("serve.forecast.field_bytes", field_bytes)
         done = time.perf_counter()
         for req, member in zip(group, members):
             req.done_ts = done

@@ -283,7 +283,9 @@ def test_nan_injected_request_fails_alone():
     rid2 = srv.submit(hdiff_program(), clean[2])
     done = {r.rid: r for r in srv.run_until_idle()}
 
-    assert srv.stats == {"batches": 1, "members": 3, "completed": 2, "failed": 1}
+    # Three members' fields read and written, the failed member's too.
+    assert srv.stats == {"batches": 1, "members": 3, "completed": 2, "failed": 1,
+                         "field_bytes": 2 * 3 * 2 * 16 * 16 * 4}
     bad = done[rid_bad]
     assert bad.done and bad.failed and bad.result is None
     assert isinstance(bad.error, NumericsError)

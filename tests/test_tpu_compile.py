@@ -25,6 +25,7 @@ from repro.configs.hdiff import CONFIG
 from repro.ir import (
     build_backend,
     hdiff_coupled_program,
+    hdiff_fields_program,
     hdiff_program,
     jacobi1d_program,
     lower_batched,
@@ -36,6 +37,7 @@ from repro.ir import (
 from repro.kernels.hdiff.kernel import hdiff_pallas
 from repro.kernels.hdiff.ops import _pick_block_rows
 from repro.kernels.stencil2d.kernel import jacobi1d_pallas
+from repro.serve.forecast import _marshal
 
 PAPER_GRID = (CONFIG.depth, CONFIG.rows, CONFIG.cols)  # 64 x 256 x 256
 V5E_HBM_BYTES = 16 * 1024**3
@@ -173,3 +175,14 @@ def test_1d_kernel_block_compiles(one_chip, which):
         def fn(x):
             return jacobi1d_pallas(x, interpret=False)
     _compile_checked(fn, _shape((64, 4096), one_chip))
+
+
+def test_cosmoe_member_batch_compiles(one_chip):
+    """The served call of a batch of 8 COSMO-E members (five 60 x 390 x 582
+    fields each): member stack, 390 -> 400 row pad under vmap, the
+    five-field kernel's 40-row tiles at 582 lanes, crop and slices."""
+    program = hdiff_fields_program(("u", "v", "w", "t", "pp"), (0.025,) * 5)
+    call = _marshal(lower_batched(program, backend="pallas", interpret=False),
+                    program.inputs)
+    members = tuple(_fields(program, (60, 390, 582), one_chip) for _ in range(8))
+    _compile_checked(call, members)
