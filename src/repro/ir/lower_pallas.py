@@ -58,7 +58,7 @@ from repro.ir.evaluate import (
     slab_sweep,
 )
 from repro.ir.graph import StencilProgram
-from repro.ir.plan import padded_rows, pick_block_rows, resident_blocks
+from repro.ir.plan import grid_block_rows, pick_block_rows, resident_blocks
 from repro.obs import metrics
 from repro.runtime import pallas_interpret
 
@@ -186,10 +186,11 @@ def lower_pallas(
         with a per-field three-slab halo sized by that field's composed
         radius (``field_radii``), so a radius-0 coefficient field streams
         exactly one block per tile and no neighbour blocks.
-      block_rows: VMEM row-tile override; default picks the largest aligned
-        divisor of rows fitting the shared VMEM budget (>= the inferred
-        chain halo), first zero-padding the rows when no aligned divisor
-        fits (``repro.ir.plan.padded_rows``; the pad is cropped off).
+      block_rows: VMEM row-tile override, which must divide the rows;
+        default picks the largest aligned divisor of rows fitting the shared
+        VMEM budget (>= the inferred chain halo), or, when no aligned
+        divisor fits, a fitting aligned tile whose ``cdiv`` grid leaves the
+        last tile ragged (``repro.ir.plan.grid_block_rows``).
       vmem_budget: VMEM byte budget for the kernel's pipelined blocks
         (arg > env > 4 MiB; see ``repro.ir.plan``).
       interpret: force interpreter mode; default = interpret iff not on TPU
@@ -227,20 +228,19 @@ def lower_pallas(
     kernel_name = _kernel_name(program)
 
     @functools.partial(
-        jax.jit, static_argnames=("br", "rows_p", "interp", "col_sharded")
+        jax.jit, static_argnames=("br", "interp", "col_sharded")
     )
-    def _call(arrays, row_offset, rows_global, col_offset, cols_global, br, rows_p,
+    def _call(arrays, row_offset, rows_global, col_offset, cols_global, br,
               interp, col_sharded):
         depth, rows, cols = arrays[0].shape
-        if rows_p > rows:
-            # Zero rows below the grid (``padded_rows``): a kept point reads
-            # at most its chain radius away, which stays inside the real rows
-            # (the bottom ring keeps its own values), so the pad is never read
-            # into a kept output and is cropped off below.
-            pad = ((0, 0), (0, rows_p - rows), (0, 0))
-            with jax.named_scope(f"ir/{program.name}/pad"):
-                arrays = [jnp.pad(a, pad) for a in arrays]
-        row_tiles = rows_p // br
+        # When ``br`` does not divide the rows (``grid_block_rows``) the last
+        # tile overhangs them: Pallas leaves its out-of-bounds reads
+        # unspecified and drops its out-of-bounds writes. No overhang value
+        # reaches a kept point. An interior point reads at most the chain
+        # radius away, which stays inside the real rows; the bottom ring rows
+        # pass through by absolute index (``rows_global``), selected with
+        # ``jnp.where``, so even a NaN read past the end never blends in.
+        row_tiles = pl.cdiv(rows, br)
         meta = jnp.stack(
             [
                 jnp.asarray(row_offset, jnp.int32),
@@ -305,24 +305,22 @@ def lower_pallas(
                 interpret=interp,
                 name=kernel_name,
             )(*operands, meta)
-        if rows_p > rows:
-            with jax.named_scope(f"ir/{program.name}/crop"):
-                outs = [o[:, :rows] for o in outs]
         return outs[0] if len(out_fields) == 1 else dict(zip(out_fields, outs))
 
     def fn(x: Array | Mapping[str, Array], *, row_offset=0, rows_global=None,
            col_offset=0, cols_global=None) -> Array:
         arrays = resolve_field_arrays(program, x, ndim=3)
         _, rows, cols = arrays[0].shape
-        br, rows_p = block_rows, rows
+        br = block_rows
         if br is None:
-            plan = dict(
-                itemsize=arrays[0].dtype.itemsize, budget_bytes=vmem_budget,
-                min_rows=min(min_block, rows), resident=resident,
+            br = grid_block_rows(
+                rows, cols, resident=resident, itemsize=arrays[0].dtype.itemsize,
+                budget_bytes=vmem_budget, min_rows=min(min_block, rows),
             )
-            rows_p = padded_rows(rows, cols, **plan)
-            br = pick_block_rows(rows_p, cols, **plan)
-        if rows_p % br:
+            if rows % br:
+                # Host builds and traces of a ragged grid, not device calls.
+                metrics.inc("ir.lower_pallas.ragged_builds")
+        elif rows % br:
             raise ValueError(f"rows={rows} not divisible by block_rows={br}")
         if br < min_block:
             raise ValueError(
@@ -338,8 +336,8 @@ def lower_pallas(
         if cols_global is None:
             cols_global = cols
         return _call(
-            arrays, row_offset, rows_global, col_offset, cols_global, br, rows_p,
-            interp, col_sharded,
+            arrays, row_offset, rows_global, col_offset, cols_global, br, interp,
+            col_sharded,
         )
 
     # Per-call timer/counter under the repro.obs registry (no-op when

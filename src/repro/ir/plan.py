@@ -6,7 +6,8 @@ three slabs (prev / cur / next) per haloed input field, one block per
 halo-free input and per output, each double-buffered (the shimDMA
 ping-pong of §3.2.1) — :func:`resident_blocks` counts them, and
 :func:`pick_block_rows` sizes the tile so that many blocks fit
-(:func:`padded_rows` pads a grid whose only aligned tile is too big). The rest
+(:func:`grid_block_rows` gives a grid whose only aligned tile is too big a
+``cdiv`` grid of fitting tiles, the last one ragged). The rest
 of the chip's scoped VMEM is left to the kernel body's temporaries. The
 budget defaults to 4 MiB and is configurable per call (``budget_bytes``)
 or process-wide via the ``REPRO_VMEM_BUDGET`` environment variable
@@ -86,7 +87,7 @@ def pick_block_rows(
     kernel's structural floor (e.g. the three-slab halo trick needs
     ``block_rows >= halo``). If no candidate fits the budget, the smallest
     candidate >= ``min_rows`` is returned (correctness over budget; see
-    :func:`padded_rows` for the remedy). If ``min_rows`` exceeds every
+    :func:`grid_block_rows` for the remedy). If ``min_rows`` exceeds every
     divisor of ``rows`` (i.e. ``rows`` itself), no tiling can satisfy the
     kernel's floor — that raises rather than silently handing back an
     undersized tile.
@@ -107,7 +108,7 @@ def pick_block_rows(
     return fallback
 
 
-def padded_rows(
+def grid_block_rows(
     rows: int,
     cols: int,
     *,
@@ -116,15 +117,15 @@ def padded_rows(
     budget_bytes: int | None = None,
     min_rows: int = 1,
 ) -> int:
-    """Rows to pad a grid to so that :func:`pick_block_rows` finds a tile
-    that fits the budget.
+    """Row tile of a ``cdiv(rows, tile)`` grid whose blocks fit the budget.
 
-    ``rows`` itself when it already does. Otherwise (typically a
-    halo-padded grid, ``rows + 2 * halo``, that no multiple of 8 divides,
-    whose only aligned tile is the whole dim) the rows are split into the
-    fewest tiles no taller than the tallest aligned tile that fits, each
-    rounded up to a multiple of ``SUBLANES`` (and to ``min_rows``), and
-    the return is their total: at most ``SUBLANES - 1`` pad rows a tile."""
+    :func:`pick_block_rows`' answer when it fits; that tile divides the
+    rows. Otherwise (typically a halo-padded grid, ``rows + 2 * halo``, that
+    no multiple of 8 divides, whose only aligned tile is the whole dim) the
+    rows are split into the fewest tiles no taller than the tallest aligned
+    tile that fits, and the tile is their even share rounded up to a
+    multiple of ``SUBLANES`` (and to ``min_rows``), never more than
+    ``rows``. The grid's last tile then overhangs the rows."""
     budget = vmem_tile_budget(budget_bytes)
     per_row = resident * cols * itemsize
     own = pick_block_rows(
@@ -132,13 +133,13 @@ def padded_rows(
         budget_bytes=budget_bytes, min_rows=min_rows,
     )
     if per_row * own <= budget:
-        return rows
+        return own
     fit = max(
         budget // per_row // SUBLANES * SUBLANES,
         _round_up(max(min_rows, 1), SUBLANES),
     )
     tiles = -(-rows // fit)
-    return tiles * _round_up(max(-(-rows // tiles), min_rows), SUBLANES)
+    return min(_round_up(max(-(-rows // tiles), min_rows), SUBLANES), rows)
 
 
 # ---------------------------------------------------------------------------

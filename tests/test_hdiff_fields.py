@@ -24,7 +24,7 @@ from repro.ir import (
     lower_pallas,
     lower_reference,
 )
-from repro.ir.plan import padded_rows, pick_block_rows, resident_blocks
+from repro.ir.plan import grid_block_rows, pick_block_rows, resident_blocks
 from repro.obs import metrics
 from repro.serve import ForecastServer
 
@@ -100,12 +100,11 @@ def test_a_wrong_coefficient_on_one_field_is_caught(backend):
 def test_cosmoe_member_plans_padded_40_row_tiles():
     """Five haloed inputs and five outputs keep 40 blocks in VMEM, so at 582
     lanes the 4 MiB budget takes 40-row tiles; 390 rows have no aligned
-    divisor, so they pad to 400."""
+    divisor, so ten 40-row tiles, the last one holding 30 rows."""
     resident = resident_blocks(5, 0, 5)
     assert resident == 40
     assert pick_block_rows(390, 582, resident=resident) == 390  # whole dim only
-    assert padded_rows(390, 582, resident=resident, min_rows=2) == 400
-    assert pick_block_rows(400, 582, resident=resident, min_rows=2) == 40
+    assert grid_block_rows(390, 582, resident=resident, min_rows=2) == 40
 
 
 def test_served_batches_of_3_and_2_bit_match_unbatched_calls():

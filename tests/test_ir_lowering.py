@@ -32,6 +32,7 @@ from repro.ir import (
 )
 from repro.ir import plan_partition as plan_partition_2d
 from repro.launch.mesh import make_mesh
+from repro.obs import metrics
 
 RNG = np.random.default_rng(11)
 
@@ -206,10 +207,24 @@ def test_pallas_call_carries_the_program_name(prog, shape):
 
 
 def test_pad_and_crop_sit_under_program_scopes():
+    """A grid with no fitting aligned tile (36 rows at a 32 KiB budget) runs
+    a ragged ``cdiv`` grid: no pad before the kernel, no crop after it, and
+    one ragged build counted; an evenly tiled 32-row grid counts none.
+
+    Interpret mode emulates the last tile's overhang with a pad of its own
+    inside the kernel's scope (``ir_hdiff/``); the TPU program holds no pad
+    at all (``tests/test_tpu_compile.py``)."""
     fn = lower_pallas(hdiff_program(), interpret=True, vmem_budget=32768)
-    text = jax.jit(lambda a: fn(a)).lower(_grid(1, 36, 128)).compile().as_text()
-    scopes = set(re.findall(r'op_name="[^"]*/(ir/hdiff/(?:pad|crop))/', text))
-    assert scopes == {"ir/hdiff/pad", "ir/hdiff/crop"}
+    with metrics.using() as registry:
+        text = jax.jit(lambda a: fn(a)).lower(_grid(1, 36, 128)).compile().as_text()
+        assert registry.snapshot()["counters"]["ir.lower_pallas.ragged_builds"] == 1
+    pads = [ln for ln in text.splitlines() if re.search(r"\bpad\(", ln)]
+    assert all('/ir_hdiff/' in ln for ln in pads), pads
+    assert not re.search(r"ir/hdiff/(pad|crop)", text)
+    with metrics.using() as registry:
+        jax.jit(lambda a: fn(a)).lower(_grid(1, 32, 128)).compile()
+        counters = registry.snapshot()["counters"]
+    assert counters.get("ir.lower_pallas.ragged_builds", 0) == 0
 
 
 def test_lower_sharded_validates_axes_and_shapes():

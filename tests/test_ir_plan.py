@@ -9,7 +9,7 @@ from repro.ir.plan import (
     DEFAULT_VMEM_TILE_BUDGET,
     SUBLANES,
     VMEM_BUDGET_ENV,
-    padded_rows,
+    grid_block_rows,
     pick_block_rows,
     resident_blocks,
     vmem_tile_budget,
@@ -114,27 +114,33 @@ def test_hdiff_fused_respects_vmem_budget_argument():
 
 
 def test_padded_rows(monkeypatch):
+    """``grid_block_rows``, the row tile of a ``cdiv`` grid: a grid that
+    ``pick_block_rows`` tiles within the budget keeps that divisor, any
+    other gets the fewest fitting aligned tiles, the last one ragged."""
     monkeypatch.delenv(VMEM_BUDGET_ENV, raising=False)
-    # A grid whose own tile fits is left alone (the paper grid, and 2048
-    # rows, whose aligned 64-row divisor fits).
-    assert padded_rows(256, 256, resident=8) == 256
-    assert padded_rows(2048, 2048, resident=8) == 2048
+    # A grid whose own tile fits keeps pick_block_rows' divisor (the paper
+    # grid, and 2048 rows, whose aligned 64-row divisor fits).
+    assert grid_block_rows(256, 256, resident=8) == 256
+    assert grid_block_rows(2048, 2048, resident=8) == 64
+    assert pick_block_rows(2048, 2048, resident=8) == 64
+    # COSMO-1's 774 x 1158 forecast field: 774 rows have no aligned divisor
+    # that fits, so seven 112-row tiles, the last one holding 102 rows.
+    assert grid_block_rows(774, 1158, resident=8, min_rows=2) == 112
     # The paper grid's adjoint runs on 260 x 260 (radius-2 pad each side):
     # the whole dim is its only aligned tile and 20 such blocks overflow
-    # 4 MiB, so it pads to two 136-row tiles.
-    assert padded_rows(260, 260, resident=20, min_rows=2) == 272
-    assert pick_block_rows(272, 260, resident=20, min_rows=2) == 136
+    # 4 MiB, so two 136-row tiles.
+    assert grid_block_rows(260, 260, resident=20, min_rows=2) == 136
     # A 4x1-sharded 2048 grid's halo-padded block (512 + 2 * 2 rows): nine
-    # 64-row tiles, 60 pad rows.
-    assert padded_rows(516, 2048, resident=8, min_rows=2) == 576
-    assert pick_block_rows(576, 2048, resident=8, min_rows=2) == 64
-    # Tiles never drop below the kernel's floor.
-    assert padded_rows(20, 1 << 16, resident=1, budget_bytes=1024, min_rows=10) == 32
+    # 64-row tiles, the last one holding 4 rows.
+    assert grid_block_rows(516, 2048, resident=8, min_rows=2) == 64
+    # Tiles never drop below the kernel's floor, nor exceed the rows.
+    assert grid_block_rows(20, 1 << 16, resident=1, budget_bytes=1024, min_rows=10) == 16
+    assert grid_block_rows(20, 1 << 16, resident=1, budget_bytes=1024, min_rows=18) == 20
 
 
-def _pad_case(name, k):
+def _pad_case(name, k, rows=18):
     rng = np.random.default_rng(11)
-    shape = (2, 18, 128)  # no multiple of 8 divides 18 (nor the adjoint's 22)
+    shape = (2, rows, 128)  # no multiple of 8 divides 18 or 17 (nor 22, 21)
     if name == "hdiff":
         program = repeat(hdiff_program(), k)
         x = jnp.asarray(rng.standard_normal(shape).astype(np.float32))
@@ -144,35 +150,49 @@ def _pad_case(name, k):
             f: jnp.asarray(rng.standard_normal(shape).astype(np.float32))
             for f in program.inputs
         }
-    # Room for 8 rows of the forward's resident blocks: the 18-row whole
-    # dim overflows, so the grid pads to three 8-row tiles.
+    # Room for 8 rows of the forward's resident blocks: the whole dim
+    # overflows, so the grid runs three 8-row tiles, the last one ragged.
     resident = resident_blocks(len(program.inputs), 0, len(program.outputs))
     budget = resident * 8 * shape[-1] * 4
-    assert padded_rows(
-        18, shape[-1], resident=resident, budget_bytes=budget,
+    assert grid_block_rows(
+        rows, shape[-1], resident=resident, budget_bytes=budget,
         min_rows=program.radius,
-    ) == 24
+    ) == 8
     return program, x, budget
 
 
-@pytest.mark.parametrize("k", [1, 2])
-@pytest.mark.parametrize("name", ["hdiff", "shallow_water"])
-def test_lower_pallas_padded_rows_match_reference(name, k):
-    """Rows padded past the budget are never read into a kept point: the
-    cropped result equals the reference."""
-    program, x, budget = _pad_case(name, k)
+def _assert_matches_reference(program, x, budget, atol):
     got = lower_pallas(program, vmem_budget=budget, interpret=True)(x)
     want = lower_reference(program)(x)
     if isinstance(want, dict):
         assert set(got) == set(want)
         for f in want:
-            np.testing.assert_allclose(got[f], want[f], rtol=0, atol=1e-6)
+            np.testing.assert_allclose(got[f], want[f], rtol=0, atol=atol)
     else:
-        np.testing.assert_allclose(got, want, rtol=0, atol=1e-6)
+        np.testing.assert_allclose(got, want, rtol=0, atol=atol)
+
+
+@pytest.mark.parametrize("k", [1, 2])
+@pytest.mark.parametrize("name", ["hdiff", "shallow_water"])
+def test_lower_pallas_padded_rows_match_reference(name, k):
+    """The rows past the last tile's end are never read into a kept point:
+    the result of the ragged grid equals the reference."""
+    _assert_matches_reference(*_pad_case(name, k), atol=1e-6)
+
+
+@pytest.mark.parametrize("k", [1, 2])
+@pytest.mark.parametrize("name", ["hdiff", "shallow_water"])
+def test_lower_pallas_one_row_last_tile_matches_reference(name, k):
+    """17 rows in 8-row tiles: the last tile holds one real row, no more than
+    the chain halo, and its neighbour's ``next`` slab reaches past the end.
+    Interpret mode fills the overhang with NaN, so any leak would show."""
+    program, x, budget = _pad_case(name, k, rows=17)
+    assert program.radius >= 17 % 8
+    _assert_matches_reference(program, x, budget, atol=0)
 
 
 def test_padded_rows_gradient_matches_reference():
-    """The adjoint kernel's grid (18 + 2 * 2 = 22 rows) pads too."""
+    """The adjoint kernel's grid (18 + 2 * 2 = 22 rows) runs ragged too."""
     program, x, budget = _pad_case("hdiff", 1)
     fwd = build_backend(
         program, "pallas", differentiable=True, vmem_budget=budget, interpret=True

@@ -179,10 +179,24 @@ def test_1d_kernel_block_compiles(one_chip, which):
 
 def test_cosmoe_member_batch_compiles(one_chip):
     """The served call of a batch of 8 COSMO-E members (five 60 x 390 x 582
-    fields each): member stack, 390 -> 400 row pad under vmap, the
-    five-field kernel's 40-row tiles at 582 lanes, crop and slices."""
+    fields each): member stack, the five-field kernel's ten 40-row tiles at
+    582 lanes under vmap, the last one ragged, and slices. No row pad."""
     program = hdiff_fields_program(("u", "v", "w", "t", "pp"), (0.025,) * 5)
     call = _marshal(lower_batched(program, backend="pallas", interpret=False),
                     program.inputs)
     members = tuple(_fields(program, (60, 390, 582), one_chip) for _ in range(8))
-    _compile_checked(call, members)
+    compiled = _compile_checked(call, members)
+    assert "pad(" not in compiled.as_text()
+
+
+def test_cosmo1_forecast_scan_compiles(one_chip):
+    """The forecast cell's call: a ``lax.scan`` of hdiff steps over the
+    80 x 774 x 1158 COSMO-1 field. 774 rows run seven 112-row tiles, the
+    last one ragged, with no pad before the kernel and no crop after it."""
+    step = lower_pallas(hdiff_program(), interpret=False)
+
+    def call(x):
+        return jax.lax.scan(lambda s, _: (step(s), None), x, None, length=4)[0]
+
+    compiled = _compile_checked(call, _shape((80, 774, 1158), one_chip))
+    assert "pad(" not in compiled.as_text()
