@@ -29,10 +29,9 @@ from repro.core import plan_partition
 # the analytical model.
 _REAL_CHECK = """
 import numpy as np, jax, jax.numpy as jnp
-assert len(jax.devices()) == 8, jax.devices()  # locks backend BEFORE dryrun import
+assert len(jax.devices()) == 8, jax.devices()
 from repro.core import HALO, hdiff
-from repro.dist import halo_exchange_bytes, make_sharded_hdiff
-from repro.launch.dryrun import parse_collective_bytes
+from repro.dist import halo_exchange_bytes, make_sharded_hdiff, parse_collective_bytes
 from repro.launch.mesh import make_mesh
 
 depth, rows, cols, dshards, rshards = {depth}, {rows}, {cols}, 2, 4

@@ -55,7 +55,7 @@ from repro.ir import (
     hdiff_coupled_program, lower_reference, lower_sharded, repeat,
     smagorinsky_coeff, vadvc_program,
 )
-from repro.launch.dryrun import parse_collective_bytes
+from repro.dist import parse_collective_bytes
 
 depth, rows, cols = {depth}, {rows}, {cols}
 R, C = 2, 4
@@ -90,7 +90,7 @@ import numpy as np, jax, jax.numpy as jnp, time
 assert len(jax.devices()) == 8, jax.devices()
 from repro.dist import program_halo_exchange_bytes_per_shard
 from repro.ir import lower_reference, lower_sharded, repeat, shallow_water_program
-from repro.launch.dryrun import parse_collective_bytes
+from repro.dist import parse_collective_bytes
 
 depth, rows, cols = {depth}, {rows}, {cols}
 R, C = 2, 4

@@ -33,7 +33,6 @@ def main() -> None:
         fig12_temporal,
         fig13_multifield,
         table2_comparison,
-        wkv6_chunking,
     )
 
     benches = {
@@ -45,7 +44,6 @@ def main() -> None:
         "fig13": fig13_multifield.run,
         "table2": table2_comparison.run,
         "analytic": analytical_vs_compiled.run,
-        "wkv6": wkv6_chunking.run,
     }
     only = {s for s in args.only.split(",") if s}
 

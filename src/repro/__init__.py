@@ -1,4 +1,3 @@
-"""repro: SPARTA-on-TPU — compound weather-stencil acceleration in JAX/Pallas
-plus the multi-arch LM framework substrate (see DESIGN.md)."""
+"""repro: SPARTA-on-TPU — compound weather-stencil acceleration in JAX/Pallas."""
 
 __version__ = "1.0.0"

@@ -1,11 +1,1 @@
-"""Per-architecture configs (one module per assigned arch) + registry."""
-
-from repro.configs.base import SHAPES, ModelConfig, ShapeConfig
-from repro.configs.registry import (
-    ARCH_IDS,
-    all_cells,
-    applicable_cells,
-    get_config,
-    get_shape,
-    get_smoke_config,
-)
+"""Workload configs: ``hdiff`` is the paper's own COSMO hdiff grid."""

@@ -17,7 +17,3 @@ class HdiffConfig:
 
 
 CONFIG = HdiffConfig()
-
-
-def smoke_config() -> HdiffConfig:
-    return dataclasses.replace(CONFIG, rows=32, cols=32, depth=4, n_timesteps=3)

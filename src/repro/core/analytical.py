@@ -4,7 +4,7 @@ The paper derives per-kernel *compute cycles* (Eq. 5-7) and *memory cycles*
 (Eq. 8-10) for an AIE core (8 fp32 MACs/cycle, 2x256-bit loads/cycle) and
 uses the ratio to decide how to split hdiff across cores. We reproduce that
 model verbatim (:func:`aie_cycles`) for the faithful-reproduction benchmarks,
-and generalise it to the three-term roofline the dry-run reports:
+and generalise it to a three-term roofline:
 
     compute_s    = flops / (chips * peak_flops)
     hbm_s        = bytes / (chips * hbm_bw)

@@ -1,18 +1,17 @@
 """repro.dist: the distribution layer.
 
-Three pieces (see ROADMAP / §3.4 of the paper):
+Two pieces (see ROADMAP / §3.4 of the paper):
 
-  * :mod:`repro.dist.sharding` — logical-axis -> mesh-axis rule engine
-    (``spec_for`` / ``sharding_for`` / ``tree_shardings``), the ambient
-    mesh, and in-graph ``constrain`` annotations.
   * :mod:`repro.dist.halo` — ``make_sharded_hdiff``: shard_map domain
     decomposition of the COSMO hdiff (depth-parallel planes + radius-2
     row halo exchange), matching the single-device kernels exactly; plus
     ``exchange_halos_2d``, the rows x cols band + diagonal-corner exchange
-    behind ``repro.ir.lower_sharded``'s 2-D decomposition, and the 2-axis
-    ``halo_exchange_bytes`` wire model.
-  * :mod:`repro.dist.reduce` — ``reduce_gradients``: cross-shard
-    all-reduce with a bf16-compressed wire path.
+    behind ``repro.ir.lower_sharded``'s 2-D decomposition, the 2-axis
+    ``halo_exchange_bytes`` wire model, and ``mesh_sizes``, the one reader
+    of a mesh's axis sizes.
+  * :mod:`repro.dist.hlo` — ``parse_collective_bytes``: the per-chip
+    collective bytes of a compiled program, the measured side of the wire
+    models.
 """
 
 from repro.dist.halo import (
@@ -22,39 +21,27 @@ from repro.dist.halo import (
     halo_exchange_bytes_per_shard,
     make_sharded_hdiff,
     measured_collective_permute_bytes,
+    mesh_sizes,
     owned_rows_mask,
     program_exchange_radii,
     program_halo_exchange_bytes,
     program_halo_exchange_bytes_per_shard,
     wire_drift_report,
 )
-from repro.dist.reduce import compress_bf16, decompress_bf16, reduce_gradients
-from repro.dist.sharding import (
-    constrain,
-    sharding_for,
-    spec_for,
-    tree_shardings,
-    use_mesh,
-)
+from repro.dist.hlo import parse_collective_bytes
 
 __all__ = [
-    "constrain",
-    "compress_bf16",
-    "decompress_bf16",
     "exchange_halos_2d",
     "exchange_row_halos",
     "halo_exchange_bytes",
     "halo_exchange_bytes_per_shard",
     "make_sharded_hdiff",
     "measured_collective_permute_bytes",
+    "mesh_sizes",
     "owned_rows_mask",
+    "parse_collective_bytes",
     "program_exchange_radii",
     "program_halo_exchange_bytes",
     "program_halo_exchange_bytes_per_shard",
-    "reduce_gradients",
-    "sharding_for",
-    "spec_for",
-    "tree_shardings",
-    "use_mesh",
     "wire_drift_report",
 ]

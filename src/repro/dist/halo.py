@@ -37,7 +37,20 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.core.hdiff import HALO, _hdiff_interior, hdiff, hdiff_simple
-from repro.dist.sharding import _mesh_sizes
+from repro.dist.hlo import parse_collective_bytes
+
+
+def mesh_sizes(mesh) -> dict[str, int]:
+    """``{axis name: size}`` in the mesh's declaration order.
+
+    Duck-typed: ``axis_names`` + ``axis_sizes`` (a concrete ``Mesh`` or an
+    ``AbstractMesh``, which has no ``devices``), falling back to
+    ``devices.shape``."""
+    names = tuple(mesh.axis_names)
+    sizes = getattr(mesh, "axis_sizes", None)
+    if sizes is None:
+        sizes = mesh.devices.shape
+    return dict(zip(names, tuple(sizes)))
 
 
 def _check_band(extent: int, halo: int, what: str) -> None:
@@ -325,13 +338,9 @@ def program_halo_exchange_bytes_per_shard(
 def measured_collective_permute_bytes(step_fn, x) -> tuple[float, int]:
     """PER-CHIP collective-permute result bytes of ``step_fn`` compiled on
     input ``x`` — the *measured* side of the wire-model claims, parsed from
-    the post-SPMD HLO (``repro.launch.dryrun.parse_collective_bytes``).
+    the post-SPMD HLO (:func:`repro.dist.hlo.parse_collective_bytes`).
     Returns ``(bytes, permute_count)``. Compiles (does not execute) the
     step."""
-    import jax
-
-    from repro.launch.dryrun import parse_collective_bytes
-
     coll = parse_collective_bytes(jax.jit(step_fn).lower(x).compile().as_text())
     return (
         coll["bytes"].get("collective-permute", 0.0),
@@ -511,7 +520,7 @@ def make_sharded_hdiff(
       limit: apply the COSMO flux limiter (Eq. 2-3).
       coeff: scalar diffusion coefficient.
     """
-    sizes = _mesh_sizes(mesh)
+    sizes = mesh_sizes(mesh)
     for ax in (depth_axis, row_axis):
         if ax is not None and ax not in sizes:
             raise ValueError(f"mesh {tuple(sizes)} has no axis {ax!r}")

@@ -152,9 +152,9 @@ def lower_sharded(
     from repro.dist.halo import (
         exchange_halos_2d,
         exchange_row_halos,
+        mesh_sizes,
         program_exchange_radii,
     )
-    from repro.dist.sharding import _mesh_sizes
 
     if program.ndim != 2:
         raise ValueError("sharded lowering needs a 2-D program")
@@ -189,7 +189,7 @@ def lower_sharded(
         if depth_axis == _DEPTH_DEFAULT:
             depth_axis = "data"
 
-    sizes = _mesh_sizes(mesh)
+    sizes = mesh_sizes(mesh)
     axis_names = tuple(sizes)  # mesh declaration order (corner pair numbering)
     axes = {"depth_axis": depth_axis, "row_axis": row_axis, "col_axis": col_axis}
     for role, ax in axes.items():

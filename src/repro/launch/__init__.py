@@ -1,1 +1,1 @@
-"""Launchers: production meshes, multi-pod dry-run, train/serve drivers."""
+"""Device-mesh construction."""

@@ -1,18 +1,8 @@
-"""Production device meshes.
+"""Device meshes.
 
-``make_production_mesh`` is a FUNCTION (never a module-level constant) so
-importing this module never touches jax device state — required because the
-dry-run overrides the device count via XLA_FLAGS before first jax init,
-while tests and benchmarks must see the real single CPU device.
-
-Mesh axes:
-  single-pod:  (16, 16)      over ("data", "model")     = 256 chips
-  multi-pod:   (2, 16, 16)   over ("pod", "data", "model") = 512 chips
-
-"pod" extends the data-parallel/FSDP dimension across the inter-pod links
-(DCN or pod-to-pod ICI); "model" carries tensor/expert/sequence parallelism
-inside a pod where ICI is fastest. See repro.dist.sharding for the logical-
-axis -> mesh-axis rules.
+``make_mesh`` is a FUNCTION (never a module-level mesh) so importing this
+module never touches jax device state: a process that fakes its device
+count via XLA_FLAGS must be able to import it before the backend starts.
 """
 
 from __future__ import annotations
@@ -20,40 +10,7 @@ from __future__ import annotations
 import jax
 
 
-def _auto(n: int):
-    return (jax.sharding.AxisType.Auto,) * n
-
-
-def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
-
-
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> jax.sharding.Mesh:
-    """Arbitrary mesh for tests/small runs (e.g. (4, 2) on 8 host devices)."""
-    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
-
-
-def make_host_mesh(model: int = 1) -> jax.sharding.Mesh:
-    """Mesh over whatever devices exist (CPU tests, single-host runs)."""
-    n = len(jax.devices())
-    if n % model:
-        raise ValueError(f"{n} devices not divisible by model={model}")
-    return jax.make_mesh((n // model, model), ("data", "model"), axis_types=_auto(2))
-
-
-def mesh_axis_names(mesh: jax.sharding.Mesh) -> tuple[str, ...]:
-    return tuple(mesh.axis_names)
-
-
-def data_axes(mesh: jax.sharding.Mesh) -> tuple[str, ...]:
-    """The axes that carry batch/data parallelism (pod folds into data)."""
-    return tuple(a for a in mesh.axis_names if a in ("pod", "data"))
-
-
-def n_devices(mesh: jax.sharding.Mesh) -> int:
-    size = 1
-    for s in mesh.devices.shape:
-        size *= s
-    return size
+    """A mesh of ``shape`` over the first devices, every axis ``Auto``
+    (e.g. (4, 2) on 8 host devices, or (2, 2) on a four-chip host)."""
+    return jax.make_mesh(shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))

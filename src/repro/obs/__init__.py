@@ -23,8 +23,8 @@ Seven small pieces (see README "Observability"):
   * :mod:`repro.obs.profile` — env-gated ``jax.profiler`` trace capture
     (``REPRO_TRACE_DIR``), with per-IR-op ``named_scope`` labels.
 
-Everything downstream (``ir`` lowerings, ``dist.halo``, ``serve.engine``,
-``train.loop``, ``checkpoint.store``, the benchmark suite) reports through
+Everything downstream (``ir`` lowerings, ``dist.halo``, ``serve.forecast``,
+``train.assimilate``, ``checkpoint.store``, the benchmark suite) reports through
 this package; it imports jax lazily and nothing here initialises a backend
 at import time.
 """

@@ -4,15 +4,15 @@ Turns a :class:`repro.obs.metrics.MetricsRegistry` snapshot — counters,
 gauges (including the ``health.<field>.<stat>`` gauges the
 :class:`~repro.obs.health.HealthMonitor` maintains) and timers — into the
 Prometheus text exposition format, so a scrape endpoint in front of
-``serve.engine.BatchedServer`` (or any instrumented run) is one
+``serve.forecast.ForecastServer`` (or any instrumented run) is one
 ``metrics_text()`` call away. No HTTP server lives here: serving bytes is
 the caller's framework's job; this module only owns the wire format.
 
 Mapping rules:
 
-  * counter ``serve.prefills``      -> ``repro_serve_prefills_total``
-  * gauge   ``health.psi.nan_count``-> ``repro_health_psi_nan_count``
-  * timer   ``serve.decode_step``   -> summary ``repro_serve_decode_step_
+  * counter ``serve.forecast.batches`` -> ``repro_serve_forecast_batches_total``
+  * gauge   ``health.psi.nan_count``   -> ``repro_health_psi_nan_count``
+  * timer   ``serve.forecast.step``    -> summary ``repro_serve_forecast_step_
     seconds`` (``_count`` + ``_sum``) plus ``_seconds_min``/``_seconds_max``
     gauges (min/max aren't part of the summary type but are too useful to
     drop).

@@ -1,7 +1,7 @@
 """Lightweight metrics registry: counters, gauges, wall-clock timers.
 
 The observability substrate every instrumented layer reports through
-(``repro.ir`` lowerings, ``repro.dist.halo``, ``repro.serve.engine``,
+(``repro.ir`` lowerings, ``repro.dist.halo``, ``repro.serve.forecast``,
 ``benchmarks/common``). Design constraints, in order:
 
   * **Zero overhead when disabled.** No registry is installed by default;
@@ -106,7 +106,7 @@ class MetricsRegistry:
         """Records an externally-measured duration (seconds) under ``name``.
 
         For latencies whose start/stop points live on different call paths
-        (e.g. queue latency: stamped at submit, resolved at prefill), where
+        (e.g. queue latency: stamped at submit, resolved at dispatch), where
         a ``with`` block can't bracket the interval."""
         self.timers.setdefault(name, TimerStat()).record(dt)
 

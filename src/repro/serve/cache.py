@@ -100,7 +100,7 @@ class CacheEntry:
 class CompileCache:
     """LRU cache of lowered (and trace-probed) program callables.
 
-    ``get`` is the whole API surface the engines use: key the request,
+    ``get`` is the whole API surface the server uses: key the request,
     return the cached callable or build-and-insert it, evicting the least
     recently used entry past ``capacity``; ``get_entry`` is the same with
     the whole :class:`CacheEntry` returned. Like the metrics registry it is

@@ -1,8 +1,7 @@
 """Forecast serving: admission, vmap-batched execution, per-request health.
 
-The stencil-side counterpart of :class:`repro.serve.engine.BatchedServer`:
-instead of token lanes, the schedulable unit is a *forecast request* — one
-IR program applied to one set of initial-condition fields. The scheduler
+The schedulable unit is a *forecast request* — one IR program applied to
+one set of initial-condition fields. The scheduler
 groups compatible pending requests (same :class:`repro.serve.cache
 .CompileKey` modulo batch size: same program fingerprint, grid, dtype,
 mesh, k, backend) into ONE vmapped step over the member axis, so N
@@ -50,8 +49,6 @@ import dataclasses
 import time
 from typing import Any, Callable, Mapping
 
-import numpy as np
-
 import jax
 import jax.numpy as jnp
 
@@ -66,8 +63,7 @@ Array = jax.Array
 class ForecastRequest:
     """One tenant's forecast: a program + its initial-condition fields.
 
-    The server stamps the same telemetry trio the token server stamps on
-    :class:`repro.serve.engine.Request` — submit / dispatch / done
+    The server stamps a telemetry trio on it — submit / dispatch / done
     timestamps, queue latency, and per-request throughput
     (``items_per_sec``, where the item is one completed forecast)."""
 
@@ -354,7 +350,12 @@ class ForecastServer:
         return self.completed[start:]
 
     def metrics_text(self) -> str:
-        """Prometheus-style exposition (see ``BatchedServer.metrics_text``)."""
+        """The scrape body: the active metrics registry in the Prometheus
+        text exposition format (:func:`repro.obs.export.prometheus_text`),
+        so the server's ``serve.forecast.*`` counters, gauges and span
+        timers read as ``repro_serve_forecast_*`` series. With no registry
+        installed it is the one comment line that says so, still
+        well-formed."""
         from repro.obs.export import prometheus_text
 
         return prometheus_text()

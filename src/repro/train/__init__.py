@@ -1,16 +1,7 @@
-from repro.train.loop import (
-    SpikeDetector,
-    StepWatchdog,
-    TrainConfig,
-    batch_sharding,
-    init_train_state,
-    make_train_step,
-    train,
-)
-from repro.train.loop import shape_for_microbatches
 from repro.train.assimilate import (
     AssimilationConfig,
     FitResult,
+    SpikeDetector,
     fit_coefficient_field,
     forward_model,
     synthetic_observations,

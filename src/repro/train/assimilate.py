@@ -7,8 +7,8 @@ state. The forward model is any ``build_backend(..., differentiable=True)``
 lowering — reference for CI, Pallas or the sharded mesh for scale — so the
 fit exercises exactly the gradient path the conformance matrix certifies,
 and the optimizer is the shipped :mod:`repro.optim` stack (no separate
-"training" codepath: the same AdamW/Adafactor, global-norm clip and
-:class:`~repro.train.loop.SpikeDetector` the LLM loop uses).
+"training" codepath: the same AdamW/Adafactor and global-norm clip), with
+every loss watched by a :class:`SpikeDetector`.
 
 The 3D-Var-style setup: observations ``y = M(u0, c*)`` of a known prior
 state ``u0`` under the true coefficients ``c*``, minimise ``J(c) = mean((M(
@@ -23,16 +23,56 @@ from __future__ import annotations
 import dataclasses
 from typing import Callable, Sequence
 
+import numpy as np
+
 import jax
 import jax.numpy as jnp
 
 from repro.ir.graph import repeat
 from repro.ir.lower_batched import build_backend
 from repro.ir.programs import hdiff_coupled_program, smagorinsky_coeff
+from repro.obs import events, metrics
 from repro.optim import OptimizerConfig, clip_by_global_norm, make_optimizer
-from repro.train.loop import SpikeDetector
 
 Array = jax.Array
+
+
+class SpikeDetector:
+    """Flags loss spikes through ``repro.obs``: a loss is a spike when it
+    is non-finite, or exceeds ``factor`` x the running median of the last
+    ``window`` recorded losses (after ``warmup`` steps — the first losses
+    of a fresh fit legitimately swing). Every spike bumps the
+    ``train.loss_spikes`` counter and records a structured
+    ``train.loss_spike`` event carrying step/loss/threshold, so a loss
+    excursion is in the flight recorder with its context, not just a line
+    lost in stdout. Finite spikes still enter the history, so a genuine
+    regime change re-centres the median instead of flagging forever."""
+
+    def __init__(self, factor: float = 5.0, warmup: int = 5, window: int = 50):
+        self.factor = factor
+        self.warmup = warmup
+        self.window = window
+        self.losses: list[float] = []
+        self.spikes: list[tuple[int, float]] = []
+
+    def record(self, step: int, loss: float) -> bool:
+        loss = float(loss)
+        finite = np.isfinite(loss)
+        threshold = None
+        spike = not finite
+        if finite and len(self.losses) > self.warmup:
+            med = float(np.median(self.losses[-self.window:]))
+            if med > 0:
+                threshold = self.factor * med
+                spike = loss > threshold
+        if finite:
+            self.losses.append(loss)
+        if spike:
+            self.spikes.append((step, loss))
+            metrics.inc("train.loss_spikes")
+            events.record("train.loss_spike", step=step, loss=loss,
+                          threshold=threshold, factor=self.factor)
+        return spike
 
 
 @dataclasses.dataclass(frozen=True)
@@ -106,8 +146,8 @@ def fit_coefficient_field(
     Plain full-batch gradient descent with the shipped optimizer stack:
     ``jax.value_and_grad`` through the differentiable lowering (the derived
     adjoint sweeps), global-norm clip, AdamW/Adafactor update, every loss
-    through a :class:`~repro.train.loop.SpikeDetector` so a diverging fit
-    lands in the flight recorder like any training run."""
+    through a :class:`SpikeDetector` so a diverging fit lands in the
+    flight recorder."""
     fwd = forward_model(cfg)
     if coeff_init is None:
         coeff_init = jnp.full(u0.shape, 0.025, u0.dtype)

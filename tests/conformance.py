@@ -209,6 +209,30 @@ def assert_batched_case(
     return batched
 
 
+# -- ragged-grid cells ---------------------------------------------------------
+# Grids whose rows no fitting aligned tile divides, run at a VMEM budget
+# small enough that ``lower_pallas`` plans a ``cdiv`` row grid whose last
+# tile overhangs the rows (``repro.ir.plan.grid_block_rows``) — the path
+# the chip cells' 774- and 390-row grids take. ``rows`` has 36 rows (no
+# aligned divisor but the whole dim) on a full 128-lane row; ``rows_lanes``
+# is COSMO-E's class: 39 rows with no aligned divisor on 58 lanes, not a
+# multiple of 128. At RAGGED_BUDGET every program and k in the matrix gets
+# an 8-row tile, so the last tile holds 4 or 7 real rows (fewer than the
+# k=3 hdiff chain halo of 6) and every cell counts a ragged build.
+RAGGED_GRIDS = {
+    "rows": (2, 36, 128),
+    "rows_lanes": (2, 39, 58),
+}
+RAGGED_BUDGET = 16 * 1024
+RAGGED_MEMBERS = 2
+RAGGED_BUILDS = "ir.lower_pallas.ragged_builds"
+
+
+def ragged_builds(registry) -> float:
+    """The ragged-build counter of a metrics registry (0 when never bumped)."""
+    return registry.snapshot()["counters"].get(RAGGED_BUILDS, 0)
+
+
 def build(program, backend: str, mesh_shape: tuple[int, int], *, overlap=False):
     """The lowered ``x -> program(x)`` callable for one matrix cell."""
     if backend == "reference":

@@ -2,8 +2,8 @@
 
 Multi-device correctness runs in a subprocess with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` (the main pytest
-process must keep seeing exactly 1 device, per the dry-run contract).
-Single-process tests cover the sharding-rule logic, which is pure.
+process must keep seeing exactly 1 device). The single-process pieces of
+``repro.dist`` are covered by ``test_dist_halo_unit.py``.
 """
 
 import os
@@ -12,11 +12,6 @@ import sys
 from pathlib import Path
 
 import pytest
-import jax
-from jax.sharding import PartitionSpec as P
-
-from repro.dist.sharding import spec_for
-from repro.launch.mesh import make_host_mesh
 
 REPO = Path(__file__).resolve().parent.parent
 
@@ -39,101 +34,7 @@ def _run_subprocess(script: str, n_dev: int = 8) -> str:
 
 @pytest.mark.multidev
 def test_halo_and_compression_multidevice():
+    """Sharded hdiff == single-device hdiff on 8 fake devices: depth-parallel,
+    4- and 8-way row halos, and the unlimited variant."""
     out = _run_subprocess("_halo_check.py")
-    assert "ALL_OK" in out
-
-
-# --- sharding rules (pure logic, fake mesh via the real 1-device mesh) -------
-
-
-class FakeMesh:
-    """Duck-typed mesh: only axis_names + devices.shape are consulted."""
-
-    def __init__(self, shape, axes):
-        import numpy as np
-
-        self.axis_names = axes
-        self.devices = np.empty(shape, dtype=object)
-
-
-def test_spec_batch_folds_pod():
-    mesh = FakeMesh((2, 16, 16), ("pod", "data", "model"))
-    spec = spec_for(("batch", "seq", "embed"), mesh, (256, 4096, 1024))
-    assert spec == P(("pod", "data"), None, None)
-
-
-def test_spec_divisibility_fallback():
-    mesh = FakeMesh((16, 16), ("data", "model"))
-    # 24 heads don't divide 16 -> replicate that dim.
-    spec = spec_for(("batch", "heads", "head_dim"), mesh, (256, 24, 128))
-    assert spec == P("data", None, None)
-    # 64 heads divide 16 -> sharded.
-    spec = spec_for(("batch", "heads", "head_dim"), mesh, (256, 64, 128))
-    assert spec == P("data", "model", None)
-
-
-def test_spec_no_double_assignment():
-    mesh = FakeMesh((16, 16), ("data", "model"))
-    # Both logical axes want "model"; only the first gets it.
-    spec = spec_for(("heads", "mlp"), mesh, (64, 12288))
-    assert spec == P("model", None)
-
-
-def test_spec_decode_kv_seq():
-    mesh = FakeMesh((16, 16), ("data", "model"))
-    spec = spec_for(("batch", "kv_seq", "kv_heads", "head_dim"), mesh, (128, 32768, 8, 128), mode="decode")
-    assert spec == P("data", "model", None, None)
-    # In train mode kv_seq is replicated; 8 kv heads can't shard 16-way so
-    # they fall back to replication too.
-    spec = spec_for(("batch", "kv_seq", "kv_heads", "head_dim"), mesh, (128, 32768, 8, 128), mode="train")
-    assert spec == P("data", None, None, None)
-    # With 16 kv heads the head dim shards.
-    spec = spec_for(("batch", "kv_seq", "kv_heads", "head_dim"), mesh, (128, 32768, 16, 128), mode="train")
-    assert spec == P("data", None, "model", None)
-
-
-def test_spec_grid_axes_map_to_same_named_mesh_axes():
-    """The stencil-grid logical axes (depth, rows, cols) shard over the
-    mesh axis of the SAME name — the rule lower_sharded's mesh_shape
-    meshes rely on — with the usual divisibility fallback."""
-    mesh = FakeMesh((2, 4), ("rows", "cols"))
-    assert spec_for(("depth", "rows", "cols"), mesh, (64, 256, 256)) == P(
-        None, "rows", "cols"
-    )
-    # Indivisible dims replicate, never pad.
-    assert spec_for(("depth", "rows", "cols"), mesh, (64, 255, 256)) == P(
-        None, None, "cols"
-    )
-    mesh3 = FakeMesh((2, 2, 2), ("depth", "rows", "cols"))
-    assert spec_for(("depth", "rows", "cols"), mesh3, (8, 16, 16)) == P(
-        "depth", "rows", "cols"
-    )
-    # No same-named axis present -> replicated (e.g. the data/model mesh).
-    mesh_dm = FakeMesh((2, 4), ("data", "model"))
-    assert spec_for(("depth", "rows", "cols"), mesh_dm, (8, 16, 16)) == P(
-        None, None, None
-    )
-
-
-def test_spec_fsdp_partial_divisibility():
-    mesh = FakeMesh((2, 16, 16), ("pod", "data", "model"))
-    # dim 32 divides 32 (pod*data) -> both axes; dim 16 only divides data.
-    assert spec_for(("fsdp",), mesh, (32,)) == P(("pod", "data"))
-    assert spec_for(("fsdp",), mesh, (16,)) == P(("data",))
-
-
-def test_host_mesh_single_device():
-    mesh = make_host_mesh()
-    assert mesh.devices.size == len(jax.devices())
-
-
-@pytest.mark.multidev
-def test_moe_sharded_multidevice():
-    out = _run_subprocess("_moe_check.py")
-    assert "ALL_OK" in out
-
-
-@pytest.mark.multidev
-def test_dryrun_machinery_multidevice():
-    out = _run_subprocess("_dryrun_check.py")
     assert "ALL_OK" in out

@@ -2,9 +2,9 @@
 
 Multi-device behaviour is covered by tests/multidev/_halo_check.py (8 fake
 devices, subprocess); these tests exercise the pure pieces — halo padding,
-absolute-row ownership masks, the analytical wire model, and the bf16
-compression round trip — on the 1-device mesh so the halo logic runs in
-the fast tier-1 path.
+absolute-row ownership masks, the analytical wire model, the HLO
+collective-byte parser that measures it, and the mesh-size reader — on the
+1-device mesh so the halo logic runs in the fast tier-1 path.
 """
 
 import numpy as np
@@ -15,20 +15,16 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import HALO, hdiff, hdiff_simple
 from repro.dist import (
-    compress_bf16,
-    decompress_bf16,
     exchange_halos_2d,
     exchange_row_halos,
     halo_exchange_bytes,
     halo_exchange_bytes_per_shard,
     make_sharded_hdiff,
+    mesh_sizes,
     owned_rows_mask,
-    reduce_gradients,
+    parse_collective_bytes,
 )
-from repro.launch.dryrun import parse_collective_bytes
 from repro.launch.mesh import make_mesh
-
-BF16_REL = 2.0 ** -8  # half-ulp of bfloat16's 7-bit mantissa
 
 
 # --- ownership masks (pure) ---------------------------------------------------
@@ -209,52 +205,76 @@ def test_sharded_hdiff_validates_axes_and_shapes():
         fn(jnp.zeros((4, 4)))  # rank-2: no depth dim
 
 
-# --- bf16 compression ---------------------------------------------------------
+# --- mesh sizes -----------------------------------------------------------
 
 
-def test_bf16_roundtrip_error_bound():
-    rng = np.random.default_rng(0)
-    # magnitudes across 12 decades, both signs — bf16 keeps f32's exponent
-    # range so the bound is purely relative, never an overflow.
-    mag = 10.0 ** rng.uniform(-6, 6, size=4096)
-    x = (mag * rng.choice([-1.0, 1.0], size=mag.shape)).astype(np.float32)
-    y = np.asarray(decompress_bf16(compress_bf16(jnp.asarray(x)), jnp.float32))
-    rel = np.abs(y - x) / np.abs(x)
-    assert rel.max() <= BF16_REL * 1.001, rel.max()
+def test_mesh_sizes_reads_concrete_abstract_and_duck_typed_meshes():
+    """Axis sizes in declaration order from a concrete ``Mesh``, from an
+    ``AbstractMesh`` (which has no ``devices``) and from any object with
+    ``axis_names`` + ``devices.shape``."""
+    assert mesh_sizes(make_mesh((1, 1), ("rows", "cols"))) == {"rows": 1, "cols": 1}
+    abstract = jax.sharding.AbstractMesh((2, 4), ("rows", "cols"))
+    assert list(mesh_sizes(abstract).items()) == [("rows", 2), ("cols", 4)]
+
+    class Duck:
+        axis_names = ("data", "model")
+        devices = np.empty((8, 2), dtype=object)
+
+    assert list(mesh_sizes(Duck()).items()) == [("data", 8), ("model", 2)]
 
 
-def test_reduce_gradients_identity_on_one_shard():
-    mesh = make_mesh((1,), ("data",))
-    grads = {
-        "w": jnp.linspace(-3.0, 3.0, 64, dtype=jnp.float32).reshape(8, 8),
-        "steps": jnp.int32(12),
-    }
+# --- HLO collective bytes -------------------------------------------------
 
-    def run(method):
-        return jax.shard_map(
-            lambda g: reduce_gradients(g, ("data",), method=method),
-            mesh=mesh,
-            in_specs=({"w": P(), "steps": P()},),
-            out_specs={"w": P(), "steps": P()},
-            check_vma=False,
-        )(grads)
+# One post-SPMD HLO instruction per case, with the per-chip bytes the ring
+# cost model charges it: all-reduce 2B(g-1)/g, all-gather and all-to-all
+# B(g-1)/g, reduce-scatter B(g-1) of its output, collective-permute B.
+_HLO_CASES = {
+    "all-reduce": (
+        "%ar = f32[8,4]{1,0} all-reduce(f32[8,4]{1,0} %p), "
+        "replica_groups={{0,1,2,3}}, to_apply=%add",
+        "all-reduce", 2 * 128 * 3 / 4,
+    ),
+    "all-gather-iota-groups": (
+        "%ag = f32[16,4]{1,0} all-gather(f32[4,4]{1,0} %p), "
+        "replica_groups=[2,4]<=[8], dimensions={0}",
+        "all-gather", 256 * 3 / 4,
+    ),
+    "reduce-scatter": (
+        "%rs = f32[2,4]{1,0} reduce-scatter(f32[8,4]{1,0} %p), "
+        "replica_groups={{0,1,2,3}}, dimensions={0}, to_apply=%add",
+        "reduce-scatter", 32 * 3,
+    ),
+    "all-to-all-bf16": (
+        "%a2a = bf16[8,4]{1,0} all-to-all(bf16[8,4]{1,0} %p), "
+        "replica_groups={{0,1}}, dimensions={0}",
+        "all-to-all", 64 / 2,
+    ),
+    "collective-permute": (
+        "%cp = f32[2,8]{1,0} collective-permute(f32[2,8]{1,0} %p), "
+        "source_target_pairs={{0,1},{1,0}}",
+        "collective-permute", 64,
+    ),
+    # Async pair: the -start tuple holds input, output and u32[] contexts;
+    # the output is counted once and the -done is skipped.
+    "collective-permute-async": (
+        "%cps = (f32[2,8]{1,0}, f32[2,8]{1,0}, u32[], u32[]) "
+        "collective-permute-start(f32[2,8]{1,0} %p), source_target_pairs={{0,1}}\n"
+        "%cpd = f32[2,8]{1,0} collective-permute-done("
+        "(f32[2,8]{1,0}, f32[2,8]{1,0}, u32[], u32[]) %cps)",
+        "collective-permute", 64,
+    ),
+    "single-member-group": (
+        "%ar1 = f32[8,4]{1,0} all-reduce(f32[8,4]{1,0} %p), "
+        "replica_groups={{0}}, to_apply=%add",
+        "all-reduce", 0.0,
+    ),
+}
 
-    exact = run("none")
-    np.testing.assert_array_equal(np.asarray(exact["w"]), np.asarray(grads["w"]))
-    assert int(exact["steps"]) == 12
 
-    lossy = run("bf16")
-    err = np.abs(np.asarray(lossy["w"]) - np.asarray(grads["w"]))
-    bound = BF16_REL * np.abs(np.asarray(grads["w"])) + 1e-7
-    assert (err <= bound).all(), err.max()
-    # integer leaves bypass compression entirely
-    assert int(lossy["steps"]) == 12
-
-
-def test_reduce_gradients_rejects_unknown_method_and_empty_axes():
-    g = {"w": jnp.ones((2, 2))}
-    with pytest.raises(ValueError):
-        reduce_gradients(g, ("data",), method="fp8")
-    # no axes -> no collective context needed, grads pass through
-    out = reduce_gradients(g, ())
-    np.testing.assert_array_equal(np.asarray(out["w"]), np.asarray(g["w"]))
+@pytest.mark.parametrize("case", list(_HLO_CASES))
+def test_parse_collective_bytes_ring_model(case):
+    text, kind, want = _HLO_CASES[case]
+    coll = parse_collective_bytes("HloModule m\n" + text + "\n")
+    assert coll["counts"] == {kind: 1}
+    assert coll["bytes"][kind] == want
+    assert coll["bytes"]["total"] == want

@@ -1,36 +1,12 @@
-"""Fault-tolerance behaviours: straggler watchdog, preemption checkpoint,
-restart-resume determinism, corrupted-checkpoint detection at load."""
+"""Fault-tolerance behaviours of the checkpoint store: corrupted-checkpoint
+detection at load and the health snapshot a save records."""
 
 import json
-import os
-import signal
-import subprocess
-import sys
-from pathlib import Path
 
 import numpy as np
 import pytest
 
-from repro.checkpoint import latest_step, restore_checkpoint, save_checkpoint
-from repro.train import StepWatchdog
-
-REPO = Path(__file__).resolve().parent.parent
-
-
-def test_watchdog_flags_stragglers():
-    wd = StepWatchdog(factor=3.0, warmup=3)
-    for i in range(10):
-        assert not wd.record(i, 0.1)
-    assert wd.record(10, 0.5)          # 5x median -> flagged
-    assert not wd.record(11, 0.12)
-    assert wd.flagged == [(10, 0.5)]
-
-
-def test_watchdog_adapts_to_regime_change():
-    wd = StepWatchdog(factor=3.0, warmup=3)
-    for i in range(60):
-        wd.record(i, 0.1 if i < 30 else 0.2)  # slow drift, no flags
-    assert all(s >= 30 for s, _ in wd.flagged) or not wd.flagged
+from repro.checkpoint import restore_checkpoint, save_checkpoint
 
 
 # --- corrupted-checkpoint detection ---------------------------------------
@@ -132,80 +108,3 @@ def test_checkpoint_roundtrip_complex_leaves(tmp_path):
     assert np.isclose(h["l2"], want_l2, rtol=1e-12)
     state, _ = _restore(tmp_path, tree)  # health verification on
     np.testing.assert_array_equal(np.asarray(state["c"]), c)
-
-
-# --- train-loop health abort under buffer donation -------------------------
-
-
-def test_train_health_checkpoint_then_abort_survives_donation(tmp_path):
-    """train()'s jit_step donates (params, opt_state), so the last-healthy
-    state the loss monitor retains is deleted by the very next step unless
-    it was host-snapshotted at probe time. A diverging run (lr=1e9 goes
-    NaN at step 2) must still COMMIT the step-1 checkpoint before the
-    abort — pre-fix this crashed with 'Array has been deleted'."""
-    import dataclasses
-
-    from repro.configs import get_smoke_config
-    from repro.data import DataConfig, SyntheticLM
-    from repro.launch.mesh import make_host_mesh
-    from repro.obs.health import NumericsError
-    from repro.train import TrainConfig, train
-
-    cfg = dataclasses.replace(
-        get_smoke_config("qwen1.5-0.5b"), n_layers=2, d_model=32, n_heads=2,
-        n_kv_heads=2, head_dim=0, d_ff=64, vocab_size=64, remat=False,
-        learning_rate=1e9)
-    ds = SyntheticLM(DataConfig(seq_len=8, global_batch=4, vocab_size=64))
-    tc = TrainConfig(steps=6, ckpt_every=10_000, ckpt_dir=str(tmp_path),
-                     log_every=100, health_every=1,
-                     health_policy="checkpoint-then-abort")
-    with pytest.raises(NumericsError) as ei:
-        train(cfg, tc, make_host_mesh(), ds, log_fn=lambda *_: None)
-    assert ei.value.step == 2 and ei.value.stats["nan_count"] == 1
-    # The step-1 (last healthy) checkpoint was written from live buffers.
-    assert latest_step(tmp_path) == 1
-    meta = json.loads((tmp_path / "step_00000001" / "meta.json").read_text())
-    assert meta["extra"]["reason"] == "health-abort"
-    assert meta["health"]["nan_count"] == 0 and meta["health"]["inf_count"] == 0
-
-
-PREEMPT_SCRIPT = """
-import sys, os, signal
-sys.path.insert(0, "{src}")
-import dataclasses
-from repro.configs import get_smoke_config
-from repro.data import DataConfig, SyntheticLM
-from repro.launch.mesh import make_host_mesh
-from repro.train import TrainConfig, train
-
-cfg = dataclasses.replace(
-    get_smoke_config("qwen1.5-0.5b"), n_layers=2, d_model=32, n_heads=2,
-    n_kv_heads=2, head_dim=0, d_ff=64, vocab_size=64, remat=False)
-ds = SyntheticLM(DataConfig(seq_len=8, global_batch=4, vocab_size=64))
-tc = TrainConfig(steps=10_000, ckpt_every=10_000, ckpt_dir="{ckpt}", log_every=1)
-
-def log(msg):
-    print(msg, flush=True)
-    if "step 3" in msg:          # simulate the preemption notice mid-run
-        os.kill(os.getpid(), signal.SIGTERM)
-
-train(cfg, tc, make_host_mesh(), ds, log_fn=log)
-print("EXITED_CLEANLY", flush=True)
-"""
-
-
-@pytest.mark.multidev
-def test_sigterm_triggers_checkpoint_and_resume(tmp_path):
-    script = PREEMPT_SCRIPT.format(src=str(REPO / "src"), ckpt=str(tmp_path))
-    env = dict(os.environ)
-    env["PYTHONPATH"] = str(REPO / "src")
-    env["JAX_PLATFORMS"] = "cpu"
-    proc = subprocess.run(
-        [sys.executable, "-c", script], capture_output=True, text=True,
-        env=env, timeout=600,
-    )
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    assert "EXITED_CLEANLY" in proc.stdout
-    assert "SIGTERM" in proc.stdout
-    step = latest_step(tmp_path)
-    assert step is not None and step >= 3

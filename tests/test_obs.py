@@ -11,9 +11,8 @@ Covers the observability contracts:
     steps aside on tracer arguments (and never changes the result);
   * drift detector inside/outside tolerance + registry side channel;
   * run-report metadata (the BENCH_*.json provenance block);
-  * ``maybe_trace`` env gating;
-  * ``BatchedServer`` telemetry (queue latency, occupancy, tokens/sec) on
-    the result objects AND in the registry;
+  * ``maybe_trace`` env gating (``ForecastServer``'s telemetry is
+    covered in ``test_serve_forecast.py``);
   * the full instrumented stack on 8 fake devices (subprocess, multidev):
     measured collective bytes == per-field model with metrics on.
 """
@@ -225,95 +224,6 @@ def test_maybe_trace_captures_into_env_dir(tmp_path, monkeypatch):
         jax.block_until_ready(jnp.arange(8.0) * 2)
     # Degrades to a no-op on profiler failure, but the label dir must exist.
     assert (tmp_path / "unit").is_dir()
-
-
-# --- BatchedServer telemetry ----------------------------------------------
-
-
-def _tiny_cfg():
-    import dataclasses
-
-    from repro.configs import get_smoke_config
-
-    return dataclasses.replace(
-        get_smoke_config("qwen1.5-0.5b"),
-        n_layers=2, d_model=32, n_heads=2, n_kv_heads=2, head_dim=0,
-        d_ff=64, vocab_size=64, remat=False,
-    )
-
-
-def test_batched_server_telemetry():
-    from repro.models import build_lm
-    from repro.serve.engine import BatchedServer
-
-    cfg = _tiny_cfg()
-    params, _ = build_lm(cfg, jax.random.PRNGKey(0))
-    with metrics.using() as reg:
-        srv = BatchedServer(cfg, params, lanes=2, max_len=64)
-        for p in range(3):
-            srv.submit(np.arange(4 + p) % 64, max_new_tokens=4)
-        done = srv.run_until_idle()
-    assert len(done) == 3
-    for r in done:
-        assert r.queue_latency_s is not None and r.queue_latency_s >= 0
-        assert r.tokens_per_sec is not None and r.tokens_per_sec > 0
-        # tokens_per_sec is the back-compat alias of the workload-neutral
-        # items_per_sec field — same value through either name.
-        assert r.items_per_sec == r.tokens_per_sec
-    snap = reg.snapshot()
-    assert snap["counters"]["serve.requests_submitted"] == 3.0
-    assert snap["counters"]["serve.prefills"] == 3.0
-    # max_new_tokens=4 = 1 prefill-argmax token + 3 decode tokens/request.
-    assert snap["counters"]["serve.tokens_out"] == 9.0
-    assert snap["counters"]["serve.decode_steps"] == 9.0
-    # A drained server is idle: the occupancy gauge must read 0.0, not the
-    # last busy step's value (regression for the staleness bug where it
-    # froze at the pre-retire occupancy).
-    assert snap["gauges"]["serve.batch_occupancy"] == 0.0
-    assert snap["gauges"]["serve.tokens_per_sec"] > 0
-    assert snap["gauges"]["serve.items_per_sec"] == snap["gauges"]["serve.tokens_per_sec"]
-    assert snap["timers"]["serve.queue_latency"]["count"] == 3
-    assert snap["timers"]["serve.prefill"]["count"] == 3
-    assert snap["timers"]["serve.decode_step"]["count"] >= 3
-    # Old-style stats dict keeps working (backward compatibility).
-    assert srv.stats == {"prefills": 3, "decode_steps": 9, "tokens_out": 9}
-
-
-def test_batch_occupancy_gauge_reflects_retires():
-    """Single-stepped server: the occupancy gauge is restated AFTER each
-    step's retires (a scrape between steps must not read the pre-retire
-    value) and drops to 0.0 the moment the server goes idle."""
-    from repro.models import build_lm
-    from repro.serve.engine import BatchedServer
-
-    cfg = _tiny_cfg()
-    params, _ = build_lm(cfg, jax.random.PRNGKey(0))
-    with metrics.using() as reg:
-        srv = BatchedServer(cfg, params, lanes=2, max_len=64)
-        srv.submit(np.arange(4) % 64, max_new_tokens=2)  # retires in 1 step
-        srv.submit(np.arange(5) % 64, max_new_tokens=4)
-        assert srv.step() is True
-        # The short request retired inside this step: post-retire occupancy
-        # is 1/2, not the in-flight 2/2.
-        assert reg.snapshot()["gauges"]["serve.batch_occupancy"] == 0.5
-        while srv.step():
-            pass
-        assert reg.snapshot()["gauges"]["serve.batch_occupancy"] == 0.0
-
-
-def test_batched_server_result_fields_without_metrics():
-    """Per-request telemetry rides on the result objects even when no
-    registry is installed — callers should not need to enable metrics."""
-    from repro.models import build_lm
-    from repro.serve.engine import BatchedServer
-
-    cfg = _tiny_cfg()
-    params, _ = build_lm(cfg, jax.random.PRNGKey(0))
-    srv = BatchedServer(cfg, params, lanes=1, max_len=64)
-    srv.submit(np.arange(4) % 64, max_new_tokens=3)
-    (req,) = srv.run_until_idle()
-    assert req.queue_latency_s is not None
-    assert req.tokens_per_sec is not None and req.tokens_per_sec > 0
 
 
 # --- the instrumented stack on 8 fake devices -----------------------------

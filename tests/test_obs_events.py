@@ -16,8 +16,8 @@ Covers the event-log contracts:
     ``REPRO_EVENT_LOG``;
   * ``prometheus_text`` renders counters/gauges/timers in the exposition
     format (sanitised names, ``_total`` counters, timer summaries);
-  * ``BatchedServer`` records request-lifecycle events
-    (submit/prefill/decode/retire) and serves the exposition via
+  * ``ForecastServer`` records request-lifecycle events
+    (submit/queue_wait/step/retire) and serves the exposition via
     ``metrics_text()``.
 """
 
@@ -30,7 +30,7 @@ from pathlib import Path
 import numpy as np
 import pytest
 
-import jax
+import jax.numpy as jnp
 
 from repro.obs import events, metrics
 from repro.obs.events import EVENT_LOG_ENV, FlightRecorder
@@ -248,52 +248,44 @@ def test_prometheus_text_uses_active_registry():
         assert "repro_live_counter_total 1.0" in prometheus_text()
 
 
-# --- BatchedServer lifecycle events + exposition --------------------------
+# --- ForecastServer lifecycle events + exposition -------------------------
 
 
-def _tiny_cfg():
-    import dataclasses
-
-    from repro.configs import get_smoke_config
-
-    return dataclasses.replace(
-        get_smoke_config("qwen1.5-0.5b"),
-        n_layers=2, d_model=32, n_heads=2, n_kv_heads=2, head_dim=0,
-        d_ff=64, vocab_size=64, remat=False,
-    )
+def _hdiff_member(rng):
+    return jnp.asarray(rng.standard_normal((2, 16, 16)).astype(np.float32))
 
 
-def test_batched_server_lifecycle_events_and_metrics_text():
-    from repro.models import build_lm
-    from repro.serve.engine import BatchedServer
+def test_forecast_server_lifecycle_events_and_metrics_text():
+    from repro.ir import hdiff_program
+    from repro.serve import ForecastServer
 
-    cfg = _tiny_cfg()
-    params, _ = build_lm(cfg, jax.random.PRNGKey(0))
+    rng = np.random.default_rng(0)
     with metrics.using(), events.using() as rec:
-        srv = BatchedServer(cfg, params, lanes=2, max_len=64)
-        for p in range(2):
-            srv.submit(np.arange(4 + p) % 64, max_new_tokens=3)
+        srv = ForecastServer(max_batch=4)
+        for _ in range(2):
+            srv.submit(hdiff_program(), _hdiff_member(rng))
         done = srv.run_until_idle()
         text = srv.metrics_text()
     assert len(done) == 2
     kinds = [e.kind for e in rec.events()]
-    assert kinds.count("serve.submit") == 2
-    assert kinds.count("serve.prefill") == 2
-    assert kinds.count("serve.retire") == 2
-    assert kinds.count("serve.decode") >= 1
-    retire = rec.events("serve.retire")[0]
-    assert retire.data["tokens_out"] == 3
-    assert retire.data["tokens_per_sec"] > 0
-    # The engine's scrape body is the live registry's exposition.
-    assert "repro_serve_prefills_total 2.0" in text
-    assert "repro_serve_tokens_out_total" in text
+    assert kinds.count("serve.forecast.submit") == 2
+    assert kinds.count("serve.forecast.queue_wait") == 2
+    assert kinds.count("serve.forecast.step") == 1
+    # One retire record per request (the retire phase span carries no rid).
+    retires = [e for e in rec.events("serve.forecast.retire") if "rid" in e.data]
+    assert [e.data["rid"] for e in retires] == [r.rid for r in done]
+    for e in retires:
+        assert e.data["members"] == 2 and e.data["failed"] is False
+        assert e.data["items_per_sec"] > 0
+    # The server's scrape body is the live registry's exposition.
+    assert "repro_serve_forecast_requests_submitted_total 2.0" in text
+    assert "repro_serve_forecast_completed_total 2.0" in text
+    assert "repro_serve_forecast_step_seconds_count 1.0" in text
 
 
-def test_batched_server_metrics_text_without_registry():
-    from repro.models import build_lm
-    from repro.serve.engine import BatchedServer
+def test_forecast_server_metrics_text_without_registry():
+    from repro.serve import ForecastServer
 
-    cfg = _tiny_cfg()
-    params, _ = build_lm(cfg, jax.random.PRNGKey(0))
-    srv = BatchedServer(cfg, params, lanes=1, max_len=64)
+    assert metrics.current() is None
+    srv = ForecastServer(max_batch=1)
     assert srv.metrics_text().startswith("#")  # well-formed even disabled

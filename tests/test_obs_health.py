@@ -1,5 +1,5 @@
 """repro.obs.health: on-device field stats, the HealthMonitor policies,
-and the train-loop SpikeDetector.
+and the assimilation SpikeDetector.
 
 Covers the numerics-health contracts:
 

@@ -33,7 +33,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from conformance import assert_close, assert_equal, to_host
+from conformance import RAGGED_BUDGET, assert_close, assert_equal, ragged_builds, to_host
 from repro.ir import (
     hdiff_program,
     laplacian_program,
@@ -375,20 +375,38 @@ def _stacked(prog, fields):
     }
 
 
+# The one-call path on two kinds of grid. ``even``: the reference backend
+# on small grids. ``ragged``: the served cells' pallas backend on COSMO-E's
+# class of grid (39 rows, 58 lanes) at a VMEM budget that plans an 8-row
+# tile whose ``cdiv`` grid leaves a 7-row last tile.
+_SERVED_GRIDS = {
+    "even": ("reference", {"hdiff": (2, 16, 16), "shallow_water": (2, 12, 12)}, {}),
+    "ragged": (
+        "pallas",
+        {"hdiff": (2, 39, 58), "shallow_water": (2, 39, 58)},
+        {"interpret": True, "vmem_budget": RAGGED_BUDGET},
+    ),
+}
+
+
+@pytest.mark.parametrize("grid_id", list(_SERVED_GRIDS))
 @pytest.mark.parametrize("n", [1, 3, 8])
 @pytest.mark.parametrize("name", ["hdiff", "shallow_water"])
-def test_one_call_batch_bit_matches_slices_of_the_batched_output(name, n):
+def test_one_call_batch_bit_matches_slices_of_the_batched_output(name, n, grid_id):
     """Each member's result from the one-call path equals ``out[i]`` of the
     cache entry's own batched call on the stacked fields, bit for bit —
-    for a single-output program and for a multi-output one."""
-    prog, grid = {"hdiff": (hdiff_program(), (2, 16, 16)),
-                  "shallow_water": (shallow_water_program(), (2, 12, 12))}[name]
+    for a single-output program and for a multi-output one, on an evenly
+    tiled grid and on a ragged one."""
+    backend, grids, lower_kwargs = _SERVED_GRIDS[grid_id]
+    prog = {"hdiff": hdiff_program(), "shallow_water": shallow_water_program()}[name]
+    grid = grids[name]
     make = _noise if name == "hdiff" else _sw_fields
     fields = [make(grid, SEED + i) for i in range(n)]
-    srv = ForecastServer(max_batch=8)
+    srv = ForecastServer(backend=backend, max_batch=8, lower_kwargs=lower_kwargs)
     done = _serve(srv, prog, fields)
     assert srv.stats["batches"] == 1
-    entry = srv.cache.lookup(compile_key(prog, grid=grid, batch=n))
+    assert (ragged_builds(metrics.current()) > 0) == (grid_id == "ragged")
+    entry = srv.cache.lookup(compile_key(prog, grid=grid, backend=backend, batch=n))
     out = entry.fn(_stacked(prog, fields))
     for i, r in enumerate(done):
         want = {f: v[i] for f, v in out.items()} if isinstance(out, dict) else out[i]
