@@ -16,8 +16,8 @@ and wire round-trips k-fold per simulated step.
 Multi-field programs are first-class: declare extra inputs (coefficients,
 velocities) and every backend takes a ``{field: array}`` mapping. Halos,
 reads and wire bytes derive PER FIELD (``field_radii`` / ``reads_by_field``)
-and sum — the Pallas kernel sizes each field's three-slab halo by its own
-radius, and the sharded lowering skips the exchange for radius-0 fields.
+and sum — the Pallas kernel sizes each field's row halo by its own radius,
+and the sharded lowering skips the exchange for radius-0 fields.
 ``vadvc_program`` / ``hdiff_coupled_program`` are the shipped workloads.
 
 Multi-OUTPUT programs (coupled PDE systems) declare ``outputs={field:

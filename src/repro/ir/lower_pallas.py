@@ -2,14 +2,20 @@
 
 Generalises the hand-fused hdiff kernel (``repro.kernels.hdiff.kernel``) to
 any 2-D program: one program instance owns one row-tile of one plane; the
-row halo is provided by the three-slab trick (each input passed with block
-index maps ``i-1 / i / i+1``, clamped at the edges), and the whole DAG is
-evaluated in VMEM by ``interior_eval`` — intermediates never touch HBM, the
-paper's accumulator-residency discipline. Block shape comes from the shared
-VMEM budget planner (``repro.ir.plan``).
+row halo comes from two halo blocks (each haloed input is passed three
+times: its own ``block_rows`` tile, and the ``hb``-row blocks just above and
+just below it, clamped at the edges), and the whole DAG is evaluated in
+VMEM by ``interior_eval`` — intermediates never touch HBM, the paper's
+accumulator-residency discipline. ``hb`` is the smallest multiple of 8 (the
+sublane tile) that covers the field's halo and divides the tile
+(``_halo_block_rows``), so a tile reads ``block_rows + 2*hb`` rows of the
+field instead of three whole tiles; where no such ``hb`` exists (a tile of
+8 rows, or one that is not a multiple of 8) the neighbour blocks are whole
+tiles. Block shape comes from the shared VMEM budget planner
+(``repro.ir.plan``), which still counts three whole tiles per haloed field.
 
 Multi-field programs get N input refs, one per declared field, each with a
-three-slab halo sized by THAT field's composed radius (``field_radii``): the
+halo sized by THAT field's composed radius (``field_radii``): the
 evolving state carries the full chain radius, a destaggered velocity its own
 smaller reach, and a radius-0 coefficient field streams exactly one block
 per tile with no neighbour fetches at all. Shallower-halo fields are
@@ -58,7 +64,7 @@ from repro.ir.evaluate import (
     slab_sweep,
 )
 from repro.ir.graph import StencilProgram
-from repro.ir.plan import grid_block_rows, pick_block_rows, resident_blocks
+from repro.ir.plan import SUBLANES, grid_block_rows, pick_block_rows, resident_blocks
 from repro.obs import metrics
 from repro.runtime import pallas_interpret
 
@@ -72,24 +78,39 @@ def _kernel_name(program: StencilProgram) -> str:
     return re.sub(r"\W", "_", f"ir_{program.name}")
 
 
+def _halo_block_rows(block_rows: int, hf: int) -> int:
+    """Rows of the blocks that carry a field's ``hf``-row halo into a
+    ``block_rows`` tile: the smallest multiple of 8 (the sublane tile) that
+    covers ``hf`` and divides the tile, so the neighbour rows sit on block
+    boundaries; ``block_rows`` itself (whole neighbour tiles) where no
+    narrower block does."""
+    for hb in range(SUBLANES, block_rows, SUBLANES):
+        if hb >= hf and block_rows % hb == 0:
+            return hb
+    return block_rows
+
+
 def _generic_kernel(
     *refs, program, block_rows, halo, col_sharded, field_halos,
 ):
     """Kernel body: blocks are (1, block_rows, C); grid is (depth, row_tiles).
 
     ``refs`` lays out, per input field in ``program.inputs`` order, a
-    ``(prev, cur, next)`` three-slab triple when that field's halo is
-    nonzero or a lone ``cur`` when it is zero, followed by ``meta_ref`` and
+    ``(prev, cur, next)`` triple when that field's halo is nonzero or a
+    lone ``cur`` when it is zero, followed by ``meta_ref`` and
     one OUTPUT ref per evolving field (``program.outputs`` order — a
     coupled system writes all its updated fields from the one fused VMEM
     residency). ``field_halos[f]`` is the field's composed chain radius —
     every evolving field carries the program's full chain radius ``halo``
     (its ring rows must hold true values for the passthrough), every other
     field only the rows it is actually read at (a radius-0 coefficient
-    fetches ONE block, no neighbours). Fields with a shallower halo are
-    zero-padded up to the common ``halo`` grid — the pad rows are provably
-    never read into a kept output point (reads reach at most the field's
-    composed radius past the kept region).
+    fetches ONE block, no neighbours). ``prev`` and ``next`` are the
+    blocks of at least ``hf`` rows that end just above and start just below
+    ``cur`` (halo blocks, or whole tiles; ``_halo_block_rows``): the
+    kernel keeps their last and first ``hf`` rows. Fields with a shallower
+    halo are zero-padded up to the common ``halo`` grid — the pad rows are
+    provably never read into a kept output point (reads reach at most the
+    field's composed radius past the kept region).
 
     Each of the chain's sweeps shrinks the state slab by its own radius
     while re-applying the global radius-r ring at ABSOLUTE row indices
@@ -183,9 +204,9 @@ def lower_pallas(
       program: a 2-D IR program (scalars baked into the graph). Multi-field
         programs are first-class: pass a ``{field: array}`` mapping (all
         arrays the same shape); the kernel takes one input ref per field
-        with a per-field three-slab halo sized by that field's composed
-        radius (``field_radii``), so a radius-0 coefficient field streams
-        exactly one block per tile and no neighbour blocks.
+        with a per-field halo sized by that field's composed radius
+        (``field_radii``), so a radius-0 coefficient field streams exactly
+        one block per tile and no neighbour blocks.
       block_rows: VMEM row-tile override, which must divide the rows;
         default picks the largest aligned divisor of rows fitting the shared
         VMEM budget (>= the inferred chain halo), or, when no aligned
@@ -258,15 +279,25 @@ def lower_pallas(
             field_halos=field_halos,
         )
         spec = lambda fn: pl.BlockSpec((1, br, cols), fn)  # noqa: E731
+
+        def halo_specs(hb):
+            # ``prev`` ends at the tile's first row and ``next`` starts after
+            # its last, in units of ``hb`` rows (``hb`` divides ``br``). At
+            # the grid's edges and past the ragged last tile the clamped
+            # blocks feed only ring rows, as above.
+            per_tile, last = br // hb, pl.cdiv(rows, hb) - 1
+            band = lambda fn: pl.BlockSpec((1, hb, cols), fn)  # noqa: E731
+            return [
+                band(lambda d, i: (d, jnp.maximum(i * per_tile - 1, 0), 0)),
+                spec(lambda d, i: (d, i, 0)),
+                band(lambda d, i: (d, jnp.minimum((i + 1) * per_tile, last), 0)),
+            ]
+
         in_specs = []
         operands = []
         for f, x in zip(fields, arrays):
             if field_halos[f]:
-                in_specs += [
-                    spec(lambda d, i: (d, jnp.maximum(i - 1, 0), 0)),
-                    spec(lambda d, i: (d, i, 0)),
-                    spec(lambda d, i: (d, jnp.minimum(i + 1, row_tiles - 1), 0)),
-                ]
+                in_specs += halo_specs(_halo_block_rows(br, field_halos[f]))
                 operands += [x, x, x]
             else:
                 in_specs.append(spec(lambda d, i: (d, i, 0)))
@@ -327,6 +358,10 @@ def lower_pallas(
                 f"block_rows={br} < inferred row halo {min_block} for "
                 f"program {program.name!r}"
             )
+        hfs = [field_halos[f] for f in fields if field_halos[f]]
+        if any(_halo_block_rows(br, hf) < br for hf in hfs):
+            # Host builds and traces whose neighbour blocks are halo blocks.
+            metrics.inc("ir.lower_pallas.halo_block_builds")
         interp = pallas_interpret(interpret)
         if rows_global is None:
             rows_global = rows

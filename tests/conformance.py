@@ -29,6 +29,7 @@ deterministic ``{field: array}`` mapping (``make_fields``).
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import numpy as np
@@ -51,6 +52,7 @@ from repro.ir import (
     smagorinsky_coeff,
     vadvc_program,
 )
+from repro.ir.plan import resident_blocks
 
 GRID = (2, 48, 48)
 TOL = 1e-6
@@ -210,27 +212,76 @@ def assert_batched_case(
 
 
 # -- ragged-grid cells ---------------------------------------------------------
-# Grids whose rows no fitting aligned tile divides, run at a VMEM budget
-# small enough that ``lower_pallas`` plans a ``cdiv`` row grid whose last
-# tile overhangs the rows (``repro.ir.plan.grid_block_rows``) — the path
-# the chip cells' 774- and 390-row grids take. ``rows`` has 36 rows (no
-# aligned divisor but the whole dim) on a full 128-lane row; ``rows_lanes``
-# is COSMO-E's class: 39 rows with no aligned divisor on 58 lanes, not a
-# multiple of 128. At RAGGED_BUDGET every program and k in the matrix gets
-# an 8-row tile, so the last tile holds 4 or 7 real rows (fewer than the
-# k=3 hdiff chain halo of 6) and every cell counts a ragged build.
+# Grids whose rows no fitting aligned tile divides, at a VMEM budget small
+# enough that ``lower_pallas`` plans a ``cdiv`` row grid whose last tile
+# overhangs the rows (``repro.ir.plan.grid_block_rows``) — the path the
+# chip cells' 774- and 390-row grids take. Each grid's budget holds
+# ``tile_rows`` rows of the program's resident blocks, as the planner counts
+# them, so every program and k in the matrix plans that tile.
+#
+# ``rows`` has 36 rows (no aligned divisor but the whole dim) on a full
+# 128-lane row; ``rows_lanes`` is COSMO-E's class: 39 rows with no aligned
+# divisor on 58 lanes, not a multiple of 128. Both take 8-row tiles, so the
+# last tile holds 4 or 7 real rows (fewer than the k=3 hdiff chain halo of
+# 6), and the neighbour blocks are whole tiles. ``wide`` takes 24-row tiles
+# over 77 rows, the last holding 5, so every haloed field fetches 8-row
+# halo blocks, the chip cells' case (112- and 40-row tiles). ``whole`` is
+# the fallback: at the default budget 36 rows take one whole-dim tile, which
+# is neither ragged nor a multiple of 8, so it keeps whole neighbour tiles.
+@dataclasses.dataclass(frozen=True)
+class RaggedGrid:
+    shape: tuple[int, int, int]
+    tile_rows: int | None  # the tile the budget is sized for; None: default
+    ragged: bool  # the plan's last tile overhangs the rows
+    halo_blocks: bool  # haloed fields fetch halo blocks narrower than the tile
+
+    def budget(self, program) -> int | None:
+        """``lower_pallas``'s ``vmem_budget`` for ``program`` on this grid."""
+        if self.tile_rows is None:
+            return None
+        radii = program.exchange_radii()
+        n_haloed = sum(1 for f in program.inputs if radii[f])
+        resident = resident_blocks(
+            n_haloed, len(program.inputs) - n_haloed, len(program.outputs)
+        )
+        return resident * self.tile_rows * self.shape[-1] * 4
+
+
 RAGGED_GRIDS = {
-    "rows": (2, 36, 128),
-    "rows_lanes": (2, 39, 58),
+    "rows": RaggedGrid((2, 36, 128), 8, ragged=True, halo_blocks=False),
+    "rows_lanes": RaggedGrid((2, 39, 58), 8, ragged=True, halo_blocks=False),
+    "wide": RaggedGrid((2, 77, 128), 24, ragged=True, halo_blocks=True),
+    "whole": RaggedGrid((2, 36, 128), None, ragged=False, halo_blocks=False),
 }
+# The budget of an 8-row tile for every program of the matrix on the
+# ``rows`` and ``rows_lanes`` grids (the served-path tests use it).
 RAGGED_BUDGET = 16 * 1024
 RAGGED_MEMBERS = 2
 RAGGED_BUILDS = "ir.lower_pallas.ragged_builds"
+HALO_BLOCK_BUILDS = "ir.lower_pallas.halo_block_builds"
 
 
 def ragged_builds(registry) -> float:
     """The ragged-build counter of a metrics registry (0 when never bumped)."""
     return registry.snapshot()["counters"].get(RAGGED_BUILDS, 0)
+
+
+def halo_block_builds(registry) -> float:
+    """The halo-block-build counter of a metrics registry (0 when never
+    bumped)."""
+    return registry.snapshot()["counters"].get(HALO_BLOCK_BUILDS, 0)
+
+
+def assert_plan(registry, grid: RaggedGrid, err_msg: str = ""):
+    """The counters of one ragged cell's build say the plan it expects."""
+    assert (ragged_builds(registry) >= 1) == grid.ragged, (
+        f"{err_msg}: ragged builds {ragged_builds(registry)}, expected "
+        f"{'some' if grid.ragged else 'none'}"
+    )
+    assert (halo_block_builds(registry) >= 1) == grid.halo_blocks, (
+        f"{err_msg}: halo-block builds {halo_block_builds(registry)}, expected "
+        f"{'some' if grid.halo_blocks else 'none'}"
+    )
 
 
 def build(program, backend: str, mesh_shape: tuple[int, int], *, overlap=False):

@@ -2,10 +2,11 @@
 cells' path).
 
 The cells of ``test_conformance_ragged.py`` through ``lower_batched(...,
-backend="pallas")`` over ``RAGGED_MEMBERS`` members: the plan must take
-the ragged ``cdiv`` row grid, each member of the batched output must be
-bit-identical to the unbatched ``lower_pallas`` call on that member's
-fields, and match ``lower_reference`` at the matrix tolerance.
+backend="pallas")`` over ``RAGGED_MEMBERS`` members: the plan must be the
+one the grid expects (ragged row grid, halo blocks), each member of the
+batched output must be bit-identical to the unbatched ``lower_pallas``
+call on that member's fields, and match ``lower_reference`` at the matrix
+tolerance.
 """
 
 import pytest
@@ -13,16 +14,15 @@ import pytest
 from conformance import (
     KS,
     PROGRAMS,
-    RAGGED_BUDGET,
     RAGGED_GRIDS,
     RAGGED_MEMBERS,
     SEED,
     assert_close,
     assert_equal,
+    assert_plan,
     make_batched_fields,
     make_fields,
     member_slice,
-    ragged_builds,
     to_host,
 )
 from repro.ir import lower_batched, lower_pallas, lower_reference, repeat
@@ -35,16 +35,15 @@ from repro.obs import metrics
 def test_ragged_batched_pallas_matches_members(name, k, grid_id):
     grid = RAGGED_GRIDS[grid_id]
     prog = repeat(PROGRAMS[name](), k)
-    batched = lower_batched(
-        prog, backend="pallas", interpret=True, vmem_budget=RAGGED_BUDGET
-    )
+    budget = grid.budget(prog)
+    batched = lower_batched(prog, backend="pallas", interpret=True, vmem_budget=budget)
     with metrics.using() as registry:
-        got = to_host(batched(make_batched_fields(name, RAGGED_MEMBERS, grid)))
-        assert ragged_builds(registry) >= 1, "the plan fell back to an even grid"
-    single = lower_pallas(prog, interpret=True, vmem_budget=RAGGED_BUDGET)
+        got = to_host(batched(make_batched_fields(name, RAGGED_MEMBERS, grid.shape)))
+        assert_plan(registry, grid, err_msg=f"{name}/batched-pallas/k={k}/grid={grid_id}")
+    single = lower_pallas(prog, interpret=True, vmem_budget=budget)
     ref = lower_reference(prog)
     for i in range(RAGGED_MEMBERS):
-        x = make_fields(name, grid, SEED + i)
+        x = make_fields(name, grid.shape, SEED + i)
         tag = f"{name}/batched-pallas/k={k}/grid={grid_id}/member={i}"
         got_i = member_slice(got, i)
         assert_equal(got_i, to_host(single(x)), err_msg=f"{tag} (vs unbatched pallas)")

@@ -7,6 +7,7 @@ run, the CompoundStencil policy wrappers, the planners, and the lowering
 argument validation (including the 2-D mesh arguments).
 """
 
+import collections
 import re
 
 import numpy as np
@@ -23,6 +24,7 @@ from repro.ir import (
     ELEMENTARY_PROGRAMS,
     StencilProgram,
     affine,
+    hdiff_fields_program,
     hdiff_program,
     jacobi1d_program,
     lower_pallas,
@@ -204,6 +206,30 @@ def test_pallas_call_carries_the_program_name(prog, shape):
     jaxpr = jax.make_jaxpr(lambda a: fn(a))(args)
     names = [e.params["name"] for e in _pallas_eqns(jaxpr.jaxpr)]
     assert names == [f"ir_{prog.name}"]
+
+
+@pytest.mark.parametrize("prog,shape,blocks", [
+    # COSMO-1: 774 rows in 112-row tiles, one haloed field.
+    (hdiff_program(), (80, 774, 1158), {(1, 112, 1158): 1, (1, 8, 1158): 2}),
+    # COSMO-E: five haloed fields, 390 rows in 40-row tiles.
+    (hdiff_fields_program(("u", "v", "w", "t", "pp"), (0.025,) * 5),
+     (60, 390, 582), {(1, 40, 582): 5, (1, 8, 582): 10}),
+], ids=["cosmo1", "cosmoe"])
+def test_neighbour_blocks_are_8_row_halo_blocks(prog, shape, blocks):
+    """At the chip cells' shapes each haloed field is fetched as its tile and
+    two 8-row halo blocks, not three whole tiles. Traced only, nothing
+    runs."""
+    fn = lower_pallas(prog)
+    x = jax.ShapeDtypeStruct(shape, jnp.float32)
+    args = {f: x for f in prog.inputs} if len(prog.inputs) > 1 else x
+    (eqn,) = _pallas_eqns(jax.make_jaxpr(lambda a: fn(a))(args).jaxpr)
+    mapping = eqn.params["grid_mapping"]
+    got = collections.Counter(
+        bm.transformed_block_aval.shape
+        for bm in mapping.block_mappings[: mapping.num_inputs]
+        if bm.array_aval.shape == shape
+    )
+    assert got == blocks
 
 
 def test_pad_and_crop_sit_under_program_scopes():

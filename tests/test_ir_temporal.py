@@ -109,7 +109,7 @@ def test_kstep_jacobi1d_matches_composed(k):
 
 
 def test_kstep_block_rows_down_to_chain_halo():
-    """The three-slab trick only needs block_rows >= k*r (one neighbour
+    """Whole neighbour tiles only need block_rows >= k*r (one neighbour
     block sources the whole band); the smallest legal tile must agree."""
     x = _grid(1, 16, 12)
     want = _composed(lambda a: hdiff(a, 0.025), x, 2)
