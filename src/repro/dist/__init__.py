@@ -15,6 +15,7 @@ Two pieces (see ROADMAP / §3.4 of the paper):
 """
 
 from repro.dist.halo import (
+    call_exchange_bytes,
     exchange_halos_2d,
     exchange_row_halos,
     halo_exchange_bytes,
@@ -31,6 +32,7 @@ from repro.dist.halo import (
 from repro.dist.hlo import parse_collective_bytes
 
 __all__ = [
+    "call_exchange_bytes",
     "exchange_halos_2d",
     "exchange_row_halos",
     "halo_exchange_bytes",

@@ -30,7 +30,7 @@ model benchmarked by ``benchmarks/fig10_scaling.py``.
 
 from __future__ import annotations
 
-from typing import Callable
+from typing import Callable, Mapping
 
 import jax
 import jax.numpy as jnp
@@ -333,6 +333,36 @@ def program_halo_exchange_bytes_per_shard(
         )
         for r in program_exchange_radii(program).values()
     )
+
+
+def call_exchange_bytes(
+    program,
+    grid: tuple[int, int, int],
+    shards: tuple[int, int, int],
+    itemsize: int | Mapping[str, int] = 4,
+) -> dict[str, int]:
+    """Per-chip model bytes of the exchange ONE ``lower_sharded`` call of
+    ``program`` issues, per exchanged field: ``{field: bytes}`` for a global
+    ``(D, R, C)`` ``grid`` split into ``(depth, rows, cols)`` ``shards``.
+
+    ``itemsize`` is one item size for every field or ``{field: size}``.
+    Empty where the call exchanges nothing (a radius-0 program, or neither
+    grid axis sharded); radius-0 fields are left out. Host arithmetic only,
+    so ``lower_sharded``'s recorder and ``ForecastServer`` (whose calls are
+    traced inside its batch call) count a call the same way."""
+    n_depth, n_row, n_col = shards
+    if n_row == 1 and n_col == 1:
+        return {}
+    d, r, c = grid
+    return {
+        f: halo_exchange_bytes_per_shard(
+            d // n_depth, r // n_row, c // n_col,
+            itemsize=itemsize[f] if isinstance(itemsize, Mapping) else itemsize,
+            halo=hf, row_sharded=n_row > 1, col_sharded=n_col > 1,
+        )
+        for f, hf in program_exchange_radii(program).items()
+        if hf
+    }
 
 
 def measured_collective_permute_bytes(step_fn, x) -> tuple[float, int]:

@@ -517,21 +517,15 @@ def lower_sharded(
         )
         if reg is None:
             return
-        from repro.dist.halo import halo_exchange_bytes_per_shard
+        from repro.dist.halo import call_exchange_bytes
 
-        d, r, c = arrays[0].shape
         reg.inc("halo.exchange_rounds")
-        for f, a in zip(fields, arrays):
-            hf = fhalos[f]
-            if hf:
-                reg.inc(
-                    f"halo.model_bytes.{f}",
-                    halo_exchange_bytes_per_shard(
-                        d // n_depth, r // n_row, c // n_col,
-                        itemsize=a.dtype.itemsize, halo=hf,
-                        row_sharded=n_row > 1, col_sharded=n_col > 1,
-                    ),
-                )
+        per_field = call_exchange_bytes(
+            program, arrays[0].shape, (n_depth, n_row, n_col),
+            itemsize={f: a.dtype.itemsize for f, a in zip(fields, arrays)},
+        )
+        for f, nbytes in per_field.items():
+            reg.inc(f"halo.model_bytes.{f}", nbytes)
 
     def step(x: Array | Mapping[str, Array]) -> Array:
         arrays = resolve_field_arrays(program, x, ndim=3)

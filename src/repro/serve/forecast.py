@@ -40,7 +40,15 @@ completed,failed}``. ``serve.forecast.marshal_builds`` counts the one-call
 wrappers built, one per cache entry (so one per batch size of a program
 and grid), each with a ``serve.forecast.marshal_build`` event naming its
 batch size. ``serve.forecast.field_bytes`` (also ``stats["field_bytes"]``)
-counts the logical bytes of the fields each batch reads and writes.
+counts the logical bytes of the fields each batch reads and writes. On a
+mesh, ``serve.forecast.halo_bytes`` (also ``stats["halo_bytes"]``) counts
+each batch's halo exchange: the per-chip model bytes of its one round
+(:func:`repro.dist.halo.call_exchange_bytes`), times its members, beside
+the counters ``halo.exchange_rounds`` and ``halo.model_bytes.<field>`` and
+a ``halo.exchange`` event per batch. The batch's call is traced inside
+:func:`_marshal`'s jit, where ``lower_sharded`` counts nothing, so the
+server counts it on the host from the batch's key; a batch on one chip
+counts none.
 """
 
 from __future__ import annotations
@@ -52,6 +60,7 @@ from typing import Any, Callable, Mapping
 import jax
 import jax.numpy as jnp
 
+from repro.dist.halo import call_exchange_bytes
 from repro.obs import events, metrics
 from repro.obs.health import HealthMonitor, NumericsError
 from repro.serve.cache import CompileCache, CompileKey, compile_key
@@ -165,7 +174,7 @@ class ForecastServer:
         self._next_rid = 0
         self.completed: list[ForecastRequest] = []
         self.stats = {"batches": 0, "members": 0, "completed": 0, "failed": 0,
-                      "field_bytes": 0}
+                      "field_bytes": 0, "halo_bytes": 0}
 
     # -- admission ---------------------------------------------------------
     def submit(
@@ -301,6 +310,9 @@ class ForecastServer:
         metrics.inc("serve.forecast.batches")
         metrics.inc("serve.forecast.members", n)
         metrics.inc("serve.forecast.field_bytes", field_bytes)
+        key = group[0].group_key
+        if key.mesh is not None:
+            self._record_exchange(group[0].program, key, n, batch)
         done = time.perf_counter()
         for req, member in zip(group, members):
             req.done_ts = done
@@ -327,6 +339,22 @@ class ForecastServer:
                 failed=req.failed, queue_latency_s=req.queue_latency_s,
                 items_per_sec=req.items_per_sec,
             )
+
+    def _record_exchange(self, program, key: CompileKey, n: int, batch: int) -> None:
+        """Counts a mesh batch's halo exchange: one round, in which each of
+        the ``n`` members moves the per-chip model bytes of one call."""
+        per_field = call_exchange_bytes(program, key.grid, (1, *key.mesh),
+                                        itemsize=jnp.dtype(key.dtype).itemsize)
+        if not per_field:
+            return
+        halo_bytes = n * sum(per_field.values())
+        self.stats["halo_bytes"] += halo_bytes
+        metrics.inc("serve.forecast.halo_bytes", halo_bytes)
+        metrics.inc("halo.exchange_rounds")
+        for f, nbytes in per_field.items():
+            metrics.inc(f"halo.model_bytes.{f}", n * nbytes)
+        events.record("halo.exchange", program=program.name, batch=batch,
+                      mesh=list(key.mesh), bytes=halo_bytes)
 
     def _check_member(self, req: ForecastRequest, member) -> None:
         """Force-check every output field of ONE member's result against

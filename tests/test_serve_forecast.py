@@ -283,9 +283,10 @@ def test_nan_injected_request_fails_alone():
     rid2 = srv.submit(hdiff_program(), clean[2])
     done = {r.rid: r for r in srv.run_until_idle()}
 
-    # Three members' fields read and written, the failed member's too.
+    # Three members' fields read and written, the failed member's too; no
+    # exchange on one chip.
     assert srv.stats == {"batches": 1, "members": 3, "completed": 2, "failed": 1,
-                         "field_bytes": 2 * 3 * 2 * 16 * 16 * 4}
+                         "field_bytes": 2 * 3 * 2 * 16 * 16 * 4, "halo_bytes": 0}
     bad = done[rid_bad]
     assert bad.done and bad.failed and bad.result is None
     assert isinstance(bad.error, NumericsError)
@@ -494,6 +495,24 @@ def test_served_mesh_results_keep_member_sharding():
     env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(
         [sys.executable, str(REPO / "tests" / "multidev" / "_served_check.py")],
+        capture_output=True, text=True, env=env, timeout=600,
+    )
+    assert proc.returncode == 0, f"STDOUT:\n{proc.stdout}\nSTDERR:\n{proc.stderr}"
+    assert "ALL_OK" in proc.stdout
+
+
+@pytest.mark.multidev
+def test_served_mesh_fields_match_reference_and_count_the_exchange():
+    """The COSMO-1E cell's served path at a ragged size on a 2x2 mesh of
+    fake devices: 11 five-field members as sharded-pallas batches of 8 and
+    3 match lower_reference and keep their sharding, and the server counts
+    each batch's halo exchange (none on one chip)."""
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["PYTHONPATH"] = str(REPO / "src")
+    env["JAX_PLATFORMS"] = "cpu"
+    proc = subprocess.run(
+        [sys.executable, str(REPO / "tests" / "multidev" / "_served_mesh_check.py")],
         capture_output=True, text=True, env=env, timeout=600,
     )
     assert proc.returncode == 0, f"STDOUT:\n{proc.stdout}\nSTDERR:\n{proc.stderr}"

@@ -200,3 +200,39 @@ def test_cosmo1_forecast_scan_compiles(one_chip):
 
     compiled = _compile_checked(call, _shape((80, 774, 1158), one_chip))
     assert "pad(" not in compiled.as_text()
+
+
+COSMO1E_GRID = (80, 774, 1158)
+COSMO1E_MEMBERS = 11
+
+
+@pytest.mark.parametrize("batch", [8, 3])
+def test_cosmo1e_served_batch_compiles_on_2x2(topo, batch):
+    """The COSMO-1E cell's served calls on a 2x2 mesh: ``batch`` members of
+    five 80 x 774 x 1158 fields (11 members at the default ``max_batch``
+    serve batches of 8 and 3), each member's fields split into 387 x 579
+    blocks, through the sharded-pallas path ``lower_batched`` builds (the
+    mesh of described chips in place of the default devices). The batch's
+    temporaries must stay within 11 GB a chip, beside its own members
+    (arguments and results) and the rest of the ensemble; a batch of 8
+    reckons 10.28 GB of temporaries and a peak of 14.08 GB, which counts
+    its members' arguments and results."""
+    program = hdiff_fields_program(("u", "v", "w", "t", "pp"), (0.025,) * 5)
+    mesh = Mesh(np.array(topo.devices[:4]).reshape(2, 2), ("rows", "cols"))
+    sharding = NamedSharding(mesh, P(None, "rows", "cols"))
+    step = lower_sharded(program, mesh, depth_axis=None, row_axis="rows", col_axis="cols",
+                         inner="pallas", interpret=False)
+    call = _marshal(jax.jit(jax.vmap(step)), program.inputs)
+    members = tuple(_fields(program, COSMO1E_GRID, sharding) for _ in range(batch))
+    compiled = _compile_checked(call, members)
+    text = compiled.as_text()
+    assert "collective-permute" in text
+    ma = compiled.memory_analysis()
+    per_member = ma.argument_size_in_bytes // batch
+    print(f"cosmo1e batch {batch}: temp_size_in_bytes {ma.temp_size_in_bytes}, "
+          f"peak_memory_in_bytes {ma.peak_memory_in_bytes}, {per_member} B a member")
+    assert ma.temp_size_in_bytes <= 11e9, ma.temp_size_in_bytes
+    assert ma.peak_memory_in_bytes + (COSMO1E_MEMBERS - batch) * per_member <= V5E_HBM_BYTES
+    for out in compiled.output_shardings:
+        for s in out.values():
+            assert s.is_equivalent_to(sharding, 3), s
